@@ -221,7 +221,20 @@ def _phase_bucketing(scale, batch: int = 4):
 
 def _run_four_devices(prog: str):
     """Run ``prog`` on 4 forced host devices; returns the json after its
-    RESULT line or an error dict."""
+    RESULT line or an error dict.
+
+    The child is pinned to the CPU, so its timings are CPU timings. On a
+    machine with an accelerator that would pass them off as the device's
+    (and the parent already holds the chip), so this refuses to run
+    unless the parent's own backend is the CPU.
+    """
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"this phase runs on 4 forced CPU devices, but this process "
+            f"serves on {jax.default_backend()!r}; its numbers would be "
+            f"CPU numbers. Run it with JAX_PLATFORMS=cpu, or use "
+            f"`python chip_smoke.py --four-chips` for the chips")
     res = run_forced_four_devices(["-c", prog], timeout=900)
     if res.returncode != 0:
         return {"error": res.stderr[-2000:]}
@@ -311,14 +324,14 @@ def _phase_hot_prefix(scale):
         assert jax.device_count() == 4, jax.devices()
         from repro.core.baselines import dbg_order
         from repro.core.dist import (ExchangeStats, make_distributed_cc,
-                                     make_distributed_sssp)
+                                     make_distributed_sssp, vertex_mesh)
         from repro.core.generators import powerlaw_community
 
         g0 = powerlaw_community({n}, avg_degree=10.0, seed=31)
         perm = np.asarray(dbg_order(g0))
         g = g0.apply_permutation(perm)     # hubs packed into the prefix
         inv = np.empty_like(perm); inv[perm] = np.arange(len(perm))
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = vertex_mesh(4)
         srcs = np.arange(4) * (g.num_vertices // 5)
         out = {{}}
         for kernel, frac in (("sssp", 0.15), ("cc", 0.15)):
@@ -711,11 +724,11 @@ def _phase_fused(scale):
                                      make_distributed_bfs,
                                      make_distributed_cc,
                                      make_distributed_pagerank,
-                                     make_distributed_sssp)
+                                     make_distributed_sssp, vertex_mesh)
         from repro.core.generators import powerlaw_community
 
         g = powerlaw_community({n}, avg_degree=10.0, seed=31)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = vertex_mesh(4)
         srcs = np.arange(4) * (g.num_vertices // 5)
 
         def build(kernel, stats, fused):

@@ -38,6 +38,8 @@ def main() -> None:
     args = ap.parse_args()
 
     todo = parse_only(args.only)
+    from repro.compile_cache import enable_compile_cache
+    print(f"[compile cache] {enable_compile_cache()}", flush=True)
     for name in todo:
         t0 = time.time()
         print(f"\n{'=' * 70}\n== {name}\n{'=' * 70}", flush=True)

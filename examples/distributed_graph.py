@@ -36,12 +36,12 @@ def hot_prefix_payload(g, perm, num_shards: int, prefix_frac: float = 0.1):
 def main():
     from repro.algos.graph_arrays import to_device
     from repro.algos.kernels import pagerank
-    from repro.core.dist import make_distributed_pagerank
+    from repro.core.dist import make_distributed_pagerank, vertex_mesh
     from repro.core.generators import powerlaw_community
     from repro.core.lorder import lorder
 
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = vertex_mesh(n_dev)
     print(f"[mesh] {n_dev} devices on axis 'data'")
 
     g = powerlaw_community(40_000, avg_degree=12, seed=13)

@@ -120,7 +120,7 @@ def pagerank_spmv(g: GraphArrays, spmv_src: jnp.ndarray,
                   num_iters: int = 20, damping: float = 0.85,
                   tol: float = 1e-6, *, blocks_per_tile: int,
                   num_tiles: int, n_pad: int,
-                  interpret: bool = True) -> jnp.ndarray:
+                  interpret: bool = False) -> jnp.ndarray:
     """`_pagerank` with the pull relaxation routed through the Pallas
     CSR-SpMV kernel (kernels/csr_spmv) inside the same ``while_loop``.
 

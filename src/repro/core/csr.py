@@ -96,8 +96,11 @@ class Graph:
     @cached_property
     def transpose(self) -> "Graph":
         """In-edge CSR (for pull-mode kernels)."""
-        order = np.argsort(self.indices, kind="stable")
-        t_indices = self.edge_src[order]
+        # sorting (dst, src) keys is the stable argsort by dst: edge_src
+        # ascends, so equal dsts keep their sources in ascending order
+        n = np.int64(self.num_vertices)
+        key = np.sort(self.indices.astype(np.int64) * n + self.edge_src)
+        t_indices = (key % n).astype(np.int32)
         t_indptr = np.zeros(self.num_vertices + 1, dtype=np.int64)
         np.cumsum(
             np.bincount(self.indices, minlength=self.num_vertices),
@@ -136,11 +139,11 @@ class Graph:
         np.cumsum(deg[inv], out=new_indptr[1:])
 
         gather = ranges_to_indices(self.indptr[inv], deg[inv].astype(np.int64))
-        new_indices = perm[self.indices[gather]].astype(np.int32)
-        # keep per-row neighbor lists sorted, as fresh CSR construction would
+        # keep per-row neighbor lists sorted, as fresh CSR construction
+        # would: sort (row, neighbor) keys, rows already being grouped
         row = np.repeat(np.arange(n, dtype=np.int64), deg[inv])
-        order = np.lexsort((new_indices, row))
-        new_indices = new_indices[order]
+        key = np.sort(row * n + perm[self.indices[gather]])
+        new_indices = (key % n).astype(np.int32)
         comm = None if self.communities is None else self.communities[inv]
         return Graph(new_indptr, new_indices, comm, self.name)
 
@@ -159,11 +162,11 @@ def from_edges(num_vertices: int, src, dst, *, dedup: bool = False,
     if dedup:
         keep = src != dst
         src, dst = src[keep], dst[keep]
-        key = src * np.int64(num_vertices) + dst
-        _, uniq = np.unique(key, return_index=True)
-        src, dst = src[uniq], dst[uniq]
-    order = np.argsort(src * np.int64(num_vertices) + dst, kind="stable")
-    src, dst = src[order], dst[order]
+    # edges in (src, dst) order are the sorted (src, dst) keys
+    n = np.int64(num_vertices)
+    key = src * n + dst
+    key = np.unique(key) if dedup else np.sort(key)
+    src, dst = key // n, key % n
     indptr = np.zeros(num_vertices + 1, dtype=np.int64)
     np.cumsum(np.bincount(src, minlength=num_vertices), out=indptr[1:])
     return Graph(indptr, dst.astype(np.int32), communities, name)

@@ -52,14 +52,25 @@ import dataclasses
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-try:  # jax >= 0.6 exposes shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # 0.4.x: experimental namespace
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .csr import Graph
+
+
+def vertex_mesh(num_shards: int | None = None, axis: str = "data",
+                devices=None) -> Mesh:
+    """1-D mesh over ``num_shards`` devices (all visible by default).
+
+    The axis is ``Auto``: the runners here place their own operands with
+    `NamedSharding` and call `shard_map`, and the sharded knn path lets
+    GSPMD partition a plain ``vmap``. Under JAX's default *Explicit* axes
+    that knn program is ill-typed (a scatter into a replicated beam from
+    a row-sharded query raises ``ShardingTypeError``).
+    """
+    devices = list(devices if devices is not None else jax.devices())
+    n = num_shards or len(devices)
+    return jax.make_mesh((n,), (axis,), axis_types=(AxisType.Auto,),
+                         devices=devices[:n])
 
 
 def _shard_map_norep(f, mesh, in_specs, out_specs):
@@ -67,14 +78,9 @@ def _shard_map_norep(f, mesh, in_specs, out_specs):
     all-gathered (hence genuinely replicated) array under a P(None, ...)
     out_spec, which the static checker cannot infer. The fused drivers
     need it too: their while-carries mix sharded state with replicated
-    caches/counters. The kwarg was renamed check_rep -> check_vma across
-    jax versions."""
-    try:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    caches/counters."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _partition_coo(src, dst, num_vertices: int, num_shards: int,
@@ -272,7 +278,7 @@ def make_distributed_pagerank(g: Graph, mesh: Mesh, axis: str = "data",
     def step(rank, src_e, dst_e, val_e, deg, dang):
         return _iterate(rank, src_e, dst_e, val_e, deg, dang)[None]
 
-    sharded_step = jax.jit(_shard_map(
+    sharded_step = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(axis), P(axis, None), P(axis, None), P(axis, None),
                   P(axis), P(axis)),
@@ -432,7 +438,7 @@ def _make_minrelax_runner(coo_src, coo_dst, edge_w, num_vertices: int,
         return _relax(state, _hot_view(state, cache),
                       src_e, dst_e, val_e, w_e)
 
-    sharded_hot = jax.jit(_shard_map(
+    sharded_hot = jax.jit(jax.shard_map(
         step_hot, mesh=mesh,
         in_specs=(P(None, axis), P(None, None), P(axis, None),
                   P(axis, None), P(axis, None), P(axis, None)),
@@ -580,7 +586,7 @@ def _make_bfs_frontier(g: Graph, mesh: Mesh, axis: str,
         alive = jax.lax.psum(new.any().astype(jnp.int32), axis)
         return depth, new, alive > 0
 
-    sharded_step = jax.jit(_shard_map(
+    sharded_step = jax.jit(jax.shard_map(
         step, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(),
                   P(axis, None), P(axis, None), P(axis, None)),
@@ -811,7 +817,7 @@ def make_distributed_bc(g: Graph, mesh: Mesh, axis: str = "data",
         tree = (dv == du + 1) & (du >= 0) & val_e[0]
         return du, tree
 
-    sharded_fwd_prep = jax.jit(_shard_map(
+    sharded_fwd_prep = jax.jit(jax.shard_map(
         fwd_prep, mesh=mesh,
         in_specs=(P(None, axis), P(axis, None), P(axis, None),
                   P(axis, None)),
@@ -827,7 +833,7 @@ def make_distributed_bc(g: Graph, mesh: Mesh, axis: str = "data",
         )(add_e)
         return sigma + add
 
-    sharded_fwd_step = jax.jit(_shard_map(
+    sharded_fwd_step = jax.jit(jax.shard_map(
         fwd_step, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis),
                   P(axis, None), P(axis, None), P()),
@@ -839,34 +845,35 @@ def make_distributed_bc(g: Graph, mesh: Mesh, axis: str = "data",
         du = depth[:, bsrc_e[0]]                          # src is local
         dv = full_depth[:, bdst_e[0]]
         tree = (dv == du + 1) & (du >= 0) & bval_e[0]
-        # sigma is fixed during the backward pass: gather it once and
-        # keep the replicated copy instead of re-gathering per level
+        # sigma is fixed during the backward pass: each tree edge's
+        # sigma[u]/sigma[v] is taken once here, not per level. Read per
+        # level inside the fused While, the same ratios gave dependencies
+        # off by up to 1e33 across four v5e chips.
         sig_full = jax.lax.all_gather(sigma, axis, axis=1, tiled=True)
-        return du, tree, sig_full
+        sig_v = jnp.maximum(sig_full[:, bdst_e[0]], 1e-30)
+        ratio = jnp.where(tree, sigma[:, bsrc_e[0]] / sig_v, 0.0)
+        return du, tree, ratio
 
-    sharded_bwd_prep = jax.jit(_shard_map_norep(
+    sharded_bwd_prep = jax.jit(jax.shard_map(
         bwd_prep, mesh=mesh,
         in_specs=(P(None, axis), P(None, axis), P(axis, None),
                   P(axis, None), P(axis, None)),
-        out_specs=(P(None, axis), P(None, axis), P(None, None)),
+        out_specs=(P(None, axis), P(None, axis), P(None, axis)),
     ))
 
-    def bwd_step(delta, sig_full, du, tree, bsrc_e, bdst_e, level):
+    def bwd_step(delta, ratio, du, tree, bsrc_e, bdst_e, level):
         full_delta = jax.lax.all_gather(delta, axis, axis=1, tiled=True)
         mask = tree & (du == level)
-        base = jax.lax.axis_index(axis) * per
-        sig_u = sig_full[:, base + bsrc_e[0]]
-        sig_v = jnp.maximum(sig_full[:, bdst_e[0]], 1e-30)
         contrib = jnp.where(
-            mask, sig_u / sig_v * (1.0 + full_delta[:, bdst_e[0]]), 0.0)
+            mask, ratio * (1.0 + full_delta[:, bdst_e[0]]), 0.0)
         add = jax.vmap(
             lambda c: jax.ops.segment_sum(c, bsrc_e[0], num_segments=per)
         )(contrib)
         return delta + add
 
-    sharded_bwd_step = jax.jit(_shard_map(
+    sharded_bwd_step = jax.jit(jax.shard_map(
         bwd_step, mesh=mesh,
-        in_specs=(P(None, axis), P(None, None), P(None, axis),
+        in_specs=(P(None, axis), P(None, axis), P(None, axis),
                   P(None, axis), P(axis, None), P(axis, None), P()),
         out_specs=P(None, axis),
     ))
@@ -905,13 +912,12 @@ def make_distributed_bc(g: Graph, mesh: Mesh, axis: str = "data",
             lambda c: c[1] <= max_level, fwd_body, (sigma, jnp.int32(0)))
 
         # pass 3: dependency accumulation, levels max_level-1 .. 0
-        du_b, tree_b, sig_full = bwd_prep(depth, sigma, bsrc_e, bdst_e,
-                                          bval_e)
+        du_b, tree_b, ratio = bwd_prep(depth, sigma, bsrc_e, bdst_e, bval_e)
 
         def bwd_body(c):
             delta, level = c
-            return (bwd_step(delta, sig_full, du_b, tree_b, bsrc_e,
-                             bdst_e, level), level - 1)
+            return (bwd_step(delta, ratio, du_b, tree_b, bsrc_e, bdst_e,
+                             level), level - 1)
 
         delta, _ = jax.lax.while_loop(
             lambda c: c[1] >= 0, bwd_body,
@@ -969,14 +975,14 @@ def make_distributed_bc(g: Graph, mesh: Mesh, axis: str = "data",
                 if stats is not None:
                     stats.record_dispatch()
                     stats.record_full(step_bytes)
-            du_b, tree_b, sig_full = sharded_bwd_prep(depth, sigma, bs_sh,
-                                                      bd_sh, bv_sh)
+            du_b, tree_b, ratio = sharded_bwd_prep(depth, sigma, bs_sh,
+                                                   bd_sh, bv_sh)
             if stats is not None:
                 stats.record_dispatch()
                 stats.record_full(2 * step_bytes)     # depth + sigma gathers
             delta = _put_state(np.zeros((s, n_pad), np.float32), mesh, axis)
             for level in range(max_level - 1, -1, -1):
-                delta = sharded_bwd_step(delta, sig_full, du_b, tree_b,
+                delta = sharded_bwd_step(delta, ratio, du_b, tree_b,
                                          bs_sh, bd_sh, jnp.int32(level))
                 if stats is not None:
                     stats.record_dispatch()

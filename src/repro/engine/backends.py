@@ -151,6 +151,89 @@ def estimate_device_bytes(num_vertices: int, num_edges: int,
             + 8 * batch_sources * num_vertices)
 
 
+# Device bytes one more source (or knn query) adds to a vmapped launch,
+# as (bytes per edge, bytes per vertex). The edge term is what the
+# vmapped bodies materialize per lane: BFS its gathered frontier flags,
+# SSSP its int32 relaxation candidates, BC the per-lane tree masks,
+# depths and float gathers of both Brandes passes. Fitted to the TPU
+# compiler's own `memory_analysis()` at Graph500 scale 22 on v5e (BFS
+# 1.1, SSSP 4.0, BC 17.5 bytes per edge per source, plus ~4·E bytes per
+# launch whatever S is), rounded up; tests/test_tpu_compile.py holds the
+# compiler to them.
+_SOURCE_BYTES = {"bfs": (2, 16), "sssp": (5, 16), "bc": (18, 32),
+                 "knn": (0, 8)}
+# the S-independent per-launch temporaries, in bytes per edge
+_LAUNCH_BYTES_PER_EDGE = 4
+# ...and the least a launch takes. Up to Graph500 scale 20 (E <= 2**24)
+# the v5e compiler lowers the `bfs_multi` / `bc_multi` scatters through
+# one workspace of 210-224 bytes per edge whatever S is (3.76 GB at scale
+# 20), which the per-source temporaries then share; from scale 21 it
+# does not. A floor covers both lowerings without cutting the batches
+# the larger graphs fit.
+_LAUNCH_FLOOR_BYTES = 4 << 30
+
+
+def source_state_bytes(kernel: str, num_vertices: int,
+                       num_edges: int) -> int:
+    """Device bytes each source adds to one launch of ``kernel`` (0 for
+    the source-independent kernels)."""
+    per_edge, per_vertex = _SOURCE_BYTES.get(kernel, (0, 0))
+    return per_edge * num_edges + per_vertex * num_vertices
+
+
+def launch_bytes(kernel: str, batch: int, num_vertices: int,
+                 num_edges: int) -> int:
+    """Modelled device temporaries of one ``batch``-source launch."""
+    return max(batch * source_state_bytes(kernel, num_vertices, num_edges)
+               + _LAUNCH_BYTES_PER_EDGE * num_edges, _LAUNCH_FLOOR_BYTES)
+
+
+def source_cap(kernel: str, num_vertices: int, num_edges: int,
+               free_bytes: int) -> int:
+    """Largest power-of-two source batch of ``kernel`` whose
+    `launch_bytes` fit in ``free_bytes`` of device memory (at least 1: a
+    lone source is always tried). Power of two because launches pad their
+    batch up to one (`pad_sources`)."""
+    per = source_state_bytes(kernel, num_vertices, num_edges)
+    if per <= 0:
+        raise ValueError(f"{kernel} takes no source batch")
+    if _LAUNCH_FLOOR_BYTES > free_bytes:
+        return 1
+    room = free_bytes - _LAUNCH_BYTES_PER_EDGE * num_edges
+    fit = max(room // per, 1)
+    return 1 << (int(fit).bit_length() - 1)
+
+
+def pr_path(pallas_pr: bool | str = "auto") -> str:
+    """Resolve the ``pallas_pr`` setting to the PageRank relaxation served.
+
+    ``"xla"`` is the segment-sum pull loop (`algos.kernels._pagerank`).
+    ``"pallas"`` routes the relaxation through the packed CSR-SpMV kernel
+    compiled for the TPU, ``"pallas-interpret"`` through the same kernel
+    body in the Pallas interpreter (validation only, far slower).
+
+    ``"auto"`` and ``False`` serve XLA on every platform: the TPU compiler
+    rejects the Pallas kernel (its in-kernel ``jnp.take`` is a 1-D gather,
+    and its whole-vector VMEM block caps V), so choosing it from the
+    platform would fail every PR request on the chip. ``True`` asks for
+    the compiled kernel and needs a TPU; ``"interpret"`` asks for the
+    interpreter by name.
+    """
+    if pallas_pr in ("auto", False):
+        return "xla"
+    if pallas_pr == "interpret":
+        return "pallas-interpret"
+    if pallas_pr is True:
+        if jax.default_backend() != "tpu":
+            raise ValueError(
+                "pallas_pr=True compiles the Pallas CSR-SpMV kernel for the "
+                f"TPU; the backend here is {jax.default_backend()!r}. Pass "
+                "pallas_pr='interpret' to run its interpreter instead")
+        return "pallas"
+    raise ValueError(f"pallas_pr must be 'auto', True, False or "
+                     f"'interpret'; got {pallas_pr!r}")
+
+
 # ------------------------------------------------------------------- handle
 @dataclasses.dataclass
 class PackedSpMV:
@@ -294,17 +377,7 @@ class SingleDeviceBackend:
         self.v_floor = v_floor
         self.e_floor = e_floor
         self.max_cached_executables = max_cached_executables
-        # Pallas PR relaxation: "auto" compiles the real kernel on TPU
-        # and stays off elsewhere (the XLA segment-sum path is the CPU
-        # production fallback); True forces it, falling back to the
-        # pallas interpreter off-TPU so CI without TPUs still runs the
-        # same kernel code (slow — validation, not serving).
-        on_tpu = jax.default_backend() == "tpu"
-        if pallas_pr == "auto":
-            self.pallas_pr = on_tpu
-        else:
-            self.pallas_pr = bool(pallas_pr)
-        self._pallas_interpret = not on_tpu
+        self.pr_path = pr_path(pallas_pr)
         self._cache: OrderedDict[tuple, object] = OrderedDict()
         # counters are registry instruments (obs.py); the legacy int
         # attributes below are read-through properties over them
@@ -361,7 +434,7 @@ class SingleDeviceBackend:
                            pad_to=bucket if bucket != (n, e) else None)
         self._counters["prepared"].inc()
         self._bucket_counts[bucket] = self._bucket_counts.get(bucket, 0) + 1
-        spmv = self._pack_spmv(arrays) if self.pallas_pr else None
+        spmv = self._pack_spmv(arrays) if self.pr_path != "xla" else None
         ds = _device_search(search, bucket[0]) if search is not None else None
         return GraphHandle(self.name, n, e, bucket,
                            estimate_device_bytes(*bucket), arrays=arrays,
@@ -383,7 +456,19 @@ class SingleDeviceBackend:
             weights)
         return PackedSpMV(jnp.asarray(src), jnp.asarray(dst_local),
                           jnp.asarray(val), bpt, ntiles, n_pad,
-                          self._pallas_interpret)
+                          self.pr_path == "pallas-interpret")
+
+    def source_cap(self, handle: GraphHandle, kernel: str) -> int | None:
+        """Largest batch of ``kernel`` sources that fits the free memory
+        the handle's device reports now (``bytes_limit`` less
+        ``bytes_in_use``). None where the device reports no limit — the
+        CPU — so batches stay unbounded there."""
+        device = next(iter(handle.arrays.indptr.devices()))
+        stats = device.memory_stats()
+        if not stats or "bytes_limit" not in stats:
+            return None
+        free = stats["bytes_limit"] - stats.get("bytes_in_use", 0)
+        return source_cap(kernel, *handle.bucket, free)
 
     # ------------------------------------------------------------------ run
     def _cache_get(self, key: tuple, build):
@@ -507,7 +592,7 @@ class SingleDeviceBackend:
             "queries_run": self.queries_run,
             "sources_run": self.sources_run,
             "dispatches": self._counters["dispatches"].value,
-            "pallas_pr": self.pallas_pr,
+            "pr_path": self.pr_path,
             "bucketing": {
                 "enabled": self.bucketing,
                 "graphs_prepared": self.graphs_prepared,
@@ -635,8 +720,8 @@ class ShardedBackend:
                  metrics: MetricsRegistry | None = None,
                  fused: bool = True):
         if mesh is None:
-            n = num_shards or jax.device_count()
-            mesh = jax.make_mesh((n,), (axis,))
+            from ..core.dist import vertex_mesh
+            mesh = vertex_mesh(num_shards, axis)
         self.mesh = mesh
         self.axis = axis
         self.num_shards = mesh.shape[axis]

@@ -647,7 +647,8 @@ class MicroBatchScheduler:
             for kernel, reqs in self._take_queues(gid):
                 reqs.sort(key=Request.order_key)
                 taken_reqs.extend(reqs)
-                chunks = ([reqs] if kernel in GLOBAL else self._chunks(reqs))
+                chunks = ([reqs] if kernel in GLOBAL else self._chunks(
+                    reqs, session._source_cap(entries[gid], kernel)))
                 streams.append([gid, kernel, entries[gid], chunks])
         served = 0
         try:
@@ -690,15 +691,21 @@ class MicroBatchScheduler:
             session._maybe_redecide(entries[gid])
         return served
 
-    def _chunks(self, reqs: list[Request]) -> list[list[Request]]:
-        """Greedy coalescing under the source cap, in drain order."""
-        if self.max_batch_sources is None:
+    def _chunks(self, reqs: list[Request],
+                device_cap: int | None = None) -> list[list[Request]]:
+        """Greedy coalescing under the source cap, in drain order. The cap
+        is the tighter of ``max_batch_sources`` and ``device_cap``, the
+        largest batch the device has memory for (None: no bound)."""
+        caps = [c for c in (self.max_batch_sources, device_cap)
+                if c is not None]
+        if not caps:
             return [reqs]
+        cap = min(caps)
         chunks: list[list[Request]] = []
         cur: list[Request] = []
         total = 0
         for r in reqs:
-            if cur and total + r.num_sources > self.max_batch_sources:
+            if cur and total + r.num_sources > cap:
                 chunks.append(cur)
                 cur, total = [], 0
             cur.append(r)

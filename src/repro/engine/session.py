@@ -928,6 +928,13 @@ class EngineSession:
             result = canonical_component_labels(result)
         return result, wall
 
+    def _source_cap(self, entry: GraphEntry, kernel: str) -> int | None:
+        """Largest coalesced source batch the entry's device can hold for
+        ``kernel`` (single-device placements; None = unbounded)."""
+        if entry.backend != "single":
+            return None
+        return self.executor.single.source_cap(entry.handle, kernel)
+
     def _last_exchange(self, entry: GraphEntry) -> dict | None:
         """Per-run ExchangeStats delta of the launch that just returned
         (sharded placements only — the single-device path has no

@@ -76,7 +76,7 @@ def pack_edges(t_indptr: np.ndarray, t_indices: np.ndarray,
 @functools.partial(jax.jit, static_argnames=("blocks_per_tile", "num_tiles",
                                              "n_pad", "interpret"))
 def csr_spmv_pallas(src, dst_local, val, x, *, blocks_per_tile: int,
-                    num_tiles: int, n_pad: int, interpret: bool = True):
+                    num_tiles: int, n_pad: int, interpret: bool = False):
     """y = A^T-gather-reduce(x) with A in packed edge-block form."""
     x_pad = jnp.zeros((n_pad,), x.dtype).at[: x.shape[0]].set(x)
     eb = EDGE_BLOCK

@@ -1,12 +1,12 @@
-"""Jit'd public wrapper for csr_spmv with backend dispatch.
+"""Public wrapper for csr_spmv: the XLA segment-sum path by default.
 
-On CPU (this container) the Pallas path runs in ``interpret=True`` for
-validation and the XLA segment-sum path is the production fallback; on TPU
-``use_pallas=True`` compiles the real kernel.
+``use_pallas=True`` compiles the packed Pallas kernel (TPU only; the TPU
+compiler currently rejects it, see ``engine.backends.pr_path``) and
+``interpret=True`` runs that kernel in the Pallas interpreter instead.
+Neither is chosen from the platform.
 """
 from __future__ import annotations
 
-import jax
 import numpy as np
 
 from .csr_spmv import csr_spmv_pallas, pack_edges
@@ -17,13 +17,12 @@ class SpMV:
     """Pre-packed SpMV operator bound to one graph (in-CSR)."""
 
     def __init__(self, t_indptr, t_indices, weights=None, *,
-                 use_pallas: bool | None = None, interpret: bool | None = None):
+                 use_pallas: bool = False, interpret: bool = False):
         self.t_indptr = np.asarray(t_indptr)
         self.t_indices = np.asarray(t_indices)
         self.weights = weights
-        on_tpu = jax.default_backend() == "tpu"
-        self.use_pallas = on_tpu if use_pallas is None else use_pallas
-        self.interpret = (not on_tpu) if interpret is None else interpret
+        self.use_pallas = use_pallas
+        self.interpret = interpret
         if self.use_pallas:
             (self.src, self.dst_local, self.val, self.bpt, self.ntiles,
              self.n_pad) = pack_edges(self.t_indptr, self.t_indices, weights)

@@ -65,8 +65,7 @@ def _beam_search_rows(rows: list, vecs: np.ndarray, q: np.ndarray,
                       entry: int, beam_width: int) -> list[tuple[float, int]]:
     """Host best-first search over mutable adjacency rows (build-time only;
     the serving-path mirror lives in core.baselines.knn_search_baseline)."""
-    dq = lambda v: float(_sq_dists(vecs[v][None], q)[0])
-    beam = [(dq(entry), entry)]
+    beam = [(float(_sq_dists(vecs[entry][None], q)[0]), entry)]
     expanded: set[int] = set()
     visited = {entry}
     while True:
@@ -75,18 +74,12 @@ def _beam_search_rows(rows: list, vecs: np.ndarray, q: np.ndarray,
             return beam
         _, v = min(frontier)
         expanded.add(v)
-        for w in rows[v]:
-            if w in visited:
-                continue
-            visited.add(w)
-            beam.append((dq(w), w))
+        fresh = [w for w in dict.fromkeys(rows[v]) if w not in visited]
+        if fresh:
+            visited.update(fresh)
+            beam += zip(_sq_dists(vecs[fresh], q).tolist(), fresh)
         beam.sort()
         del beam[beam_width:]
-
-
-def _sqd(vecs: np.ndarray, a: int, b: int) -> float:
-    d = vecs[a] - vecs[b]
-    return float(d @ d)
 
 
 def _diverse_k(vecs: np.ndarray, u: int, cands, k: int) -> list[int]:
@@ -97,20 +90,25 @@ def _diverse_k(vecs: np.ndarray, u: int, cands, k: int) -> list[int]:
     to the exact k-NN graph — which is *disconnected* across clusters;
     the diversity rule is what preserves the long-range edges greedy
     search needs to hop between them."""
-    order = sorted({int(c) for c in cands} - {u},
-                   key=lambda w: (_sqd(vecs, u, w), w))
+    ids = np.array(sorted({int(c) for c in cands} - {u}), dtype=np.int64)
+    pts = vecs[ids]
+    du = _sq_dists(pts, vecs[u])
+    order = np.lexsort((ids, du))          # nearest first, ties by id
+    ids, pts, du = ids[order], pts[order], du[order]
+    diff = pts[:, None, :] - pts[None, :, :]
+    # closer[i][j]: candidate i is nearer to u than to candidate j
+    closer = (du[:, None] < np.einsum("ijd,ijd->ij", diff, diff)).tolist()
     kept: list[int] = []
     skipped: list[int] = []
-    for c in order:
+    for i, row in enumerate(closer):
         if len(kept) >= k:
             break
-        dc = _sqd(vecs, u, c)
-        if all(dc < _sqd(vecs, c, s) for s in kept):
-            kept.append(c)
+        if all(row[j] for j in kept):
+            kept.append(i)
         else:
-            skipped.append(c)
+            skipped.append(i)
     kept += skipped[:k - len(kept)]
-    return kept
+    return ids[kept].tolist()
 
 
 def _nsw_connect(rows: dict, vecs: np.ndarray, new: int,
@@ -150,6 +148,58 @@ def _nsw_rows(vecs: np.ndarray, k: int, ef: int,
     return [rows[i] for i in range(len(vecs))]
 
 
+def _reachable(rows: np.ndarray, entry: int) -> np.ndarray:
+    """(n,) mask of vertices reachable from ``entry`` over (n, k) rows."""
+    seen = np.zeros(len(rows), bool)
+    seen[entry] = True
+    frontier = np.array([entry])
+    while frontier.size:
+        nbrs = np.unique(rows[frontier])
+        frontier = nbrs[~seen[nbrs]]
+        seen[frontier] = True
+    return seen
+
+
+def _reach_all(rows: np.ndarray, vecs: np.ndarray, entry: int) -> np.ndarray:
+    """Relink (n, k) rows until every vertex is reachable from ``entry``.
+
+    The diversity rule prunes the few cross-cluster links an insert
+    makes whenever a row overflows, so a whole cluster can end up with no
+    path in from the entry, and no beam search can then find any of it.
+    While some vertex ``w`` (lowest id first) is unreachable, the
+    reachable vertex nearest to it takes a link to ``w`` in place of a
+    self-loop pad or, failing that, of the neighbor with the most
+    in-links. A relink that does not grow the reachable set is undone,
+    so the loop ends.
+    """
+    reach = _reachable(rows, entry)
+    while not reach.all():
+        reach = _relink(rows, vecs, entry, reach,
+                        int(np.flatnonzero(~reach)[0]))
+    return rows
+
+
+def _relink(rows: np.ndarray, vecs: np.ndarray, entry: int,
+            reach: np.ndarray, w: int) -> np.ndarray:
+    """Give unreachable ``w`` an in-link from the nearest reachable row
+    that can spare a slot; returns the grown reachable mask."""
+    cands = np.flatnonzero(reach)
+    owners = cands[np.argsort(_sq_dists(vecs[cands], vecs[w]),
+                              kind="stable")]
+    in_deg = np.bincount(rows.ravel(), minlength=len(rows))
+    for u in owners:
+        row = rows[u]
+        for j in sorted(range(len(row)),
+                        key=lambda j: (row[j] != u, -in_deg[row[j]], j)):
+            old = row[j]
+            row[j] = w
+            grown = _reachable(rows, entry)
+            if grown.sum() > reach.sum():
+                return grown
+            row[j] = old
+    raise RuntimeError(f"no reachable row can link vertex {w}")
+
+
 def build_nsw_graph(vectors: np.ndarray, k: int, ef: int | None = None,
                     name: str = "nsw") -> Graph:
     """NSW-style incremental-insert graph: each point is beam-searched
@@ -160,10 +210,13 @@ def build_nsw_graph(vectors: np.ndarray, k: int, ef: int | None = None,
     *shuffled* order: corpora often arrive cluster-sorted (e.g.
     `core.generators.clustered_vectors`), and inserting cluster-by-cluster
     leaves no early cross-cluster links for later reverse-link
-    replacement to preserve."""
+    replacement to preserve. Pruning can still cut a cluster off, so the
+    build ends by relinking until every vertex is reachable from the
+    serving entry point (`medoid_entry`)."""
     vecs = np.asarray(vectors, np.float64)
     order = np.random.default_rng(7).permutation(len(vecs))
     rows = _nsw_rows(vecs, k, ef or 2 * k + 16, order=order)
+    rows = _reach_all(np.asarray(rows, np.int64), vecs, medoid_entry(vecs))
     src = np.repeat(np.arange(len(rows), dtype=np.int64), k)
     return from_edges(len(rows), src, np.concatenate(
         [np.asarray(r, np.int64) for r in rows]), name=name)

@@ -22,7 +22,8 @@ from repro.engine import (SHARDED_KERNELS, BatchedExecutor, EngineSession,
                           GraphHandle, GraphProbes, ReorderPolicy,
                           ShardedBackend, SingleDeviceBackend, bucket_dims,
                           estimate_device_bytes, probe_graph)
-from repro.engine.backends import _RUNNER_FACTORIES, GLOBAL, MULTI_SOURCE
+from repro.engine.backends import (_RUNNER_FACTORIES, GLOBAL, MULTI_SOURCE,
+                                   launch_bytes, source_cap)
 
 
 # ---------------------------------------------------------------- buckets
@@ -36,6 +37,51 @@ def test_bucket_dims_geometric_and_sentinel_room():
     assert bucket_dims(8, 12) == (256, 1024)
     with pytest.raises(ValueError):
         bucket_dims(10, 10, growth=1.0)
+
+
+@pytest.mark.parametrize("kernel", ["bfs", "sssp", "bc", "knn"])
+def test_source_cap_is_the_largest_power_of_two_that_fits(kernel):
+    v, e = 1 << 22, 16 << 22                 # Graph500 scale 22
+    for free in (10**8, 10**9, 8 * 10**9, 15 * 10**9):
+        cap = source_cap(kernel, v, e, free)
+        assert cap >= 1 and cap & (cap - 1) == 0
+        assert cap == 1 or launch_bytes(kernel, cap, v, e) <= free
+        assert launch_bytes(kernel, 2 * cap, v, e) > free
+
+
+# Temporaries the v5e compiler allocates for the multi-source kernels
+# (`memory_analysis().temp_size_in_bytes`, AOT compiles against a
+# described v5e, no chip), as (kernel, Graph500 scale, S, bytes). Up to
+# scale 20 bfs/bc take a ~210-224 B/edge workspace whatever S is; from
+# 21 they do not. tests/test_tpu_compile.py re-checks scale 22 live.
+_V5E_TEMPS = [
+    ("bfs", 18, 8, 872_802_304), ("bfs", 19, 8, 1_778_933_248),
+    ("bc", 19, 8, 1_729_213_440),
+    ("bfs", 20, 4, 3_758_900_000), ("bfs", 20, 8, 3_758_700_000),
+    ("bfs", 20, 16, 3_691_700_000), ("sssp", 20, 4, 268_700_000),
+    ("sssp", 20, 8, 604_300_000), ("sssp", 20, 16, 1_073_900_000),
+    ("bc", 20, 4, 3_692_500_000), ("bc", 20, 8, 3_692_300_000),
+    ("bc", 20, 16, 4_699_100_000),
+    ("bfs", 21, 8, 436_595_712), ("bc", 21, 8, 4_766_020_096),
+    ("bfs", 22, 16, 1_476_800_000), ("sssp", 22, 16, 4_563_600_000),
+    ("bc", 22, 8, 9_665_000_000),
+]
+
+
+@pytest.mark.parametrize("kernel,scale,batch,temp", _V5E_TEMPS)
+def test_launch_bytes_cover_the_v5e_compiler(kernel, scale, batch, temp):
+    v, e = 1 << scale, 16 << scale
+    assert launch_bytes(kernel, batch, v, e) >= temp
+
+
+def test_source_cap_bounds_bc_below_the_burst():
+    """BC's per-source temporaries scale with E: next to a scale-22 CSR
+    one 16 GB chip holds 8 sources, not a 32-source burst."""
+    v, e = 1 << 22, 16 << 22
+    assert source_cap("bc", v, e,
+                      16 * 10**9 - estimate_device_bytes(v, e)) == 8
+    with pytest.raises(ValueError):
+        source_cap("pr", v, e, 10**9)        # global kernels take no batch
 
 
 def test_estimate_device_bytes_monotone():
@@ -296,3 +342,15 @@ def test_run_py_parse_only_accepts_lists():
     assert parse_only(" engine , skew ") == ["engine", "skew"]
     with pytest.raises(SystemExit):
         parse_only("engine,nope")
+
+
+def test_forced_cpu_phases_refuse_an_accelerator_parent(monkeypatch):
+    """The 4-device phases run a child pinned to the CPU; from a parent
+    serving on a TPU they would pass CPU timings off as the chip's."""
+    import jax
+    from benchmarks import engine as bench_engine
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(bench_engine, "run_forced_four_devices",
+                        lambda *a, **k: pytest.fail("child started"))
+    with pytest.raises(RuntimeError, match="CPU numbers"):
+        bench_engine._run_four_devices("print('RESULT {}')")

@@ -132,14 +132,20 @@ def test_pagerank_spmv_matches_segment_sum_kernel():
 
 
 def test_engine_pallas_pr_backend_parity():
-    """`SingleDeviceBackend(pallas_pr=True)` serves PR through the packed
-    kernel (one launch per query, `pr@spmv` cache key) and matches the
-    default backend bit-for-bit up to float tolerance."""
+    """`SingleDeviceBackend(pallas_pr="interpret")` serves PR through the
+    packed kernel's interpreter (one launch per query, `pr@spmv` cache
+    key) and matches the default XLA backend up to float tolerance.
+    ``"auto"`` serves XLA, and ``True`` (the compiled kernel) refuses to
+    run anywhere but on a TPU instead of falling back to the
+    interpreter."""
     from repro.engine.backends import SingleDeviceBackend
     g = powerlaw_community(500, avg_degree=6.0, seed=7)
     ref = SingleDeviceBackend()
-    pal = SingleDeviceBackend(pallas_pr=True)
-    assert ref.telemetry()["pallas_pr"] is False  # auto -> off on CPU
+    pal = SingleDeviceBackend(pallas_pr="interpret")
+    assert ref.telemetry()["pr_path"] == "xla"
+    assert pal.telemetry()["pr_path"] == "pallas-interpret"
+    with pytest.raises(ValueError, match="interpret"):
+        SingleDeviceBackend(pallas_pr=True)        # CPU: no silent fallback
     h_ref, h_pal = ref.prepare(g), pal.prepare(g)
     assert h_ref.spmv is None and h_pal.spmv is not None
     out_ref = np.asarray(ref.run(h_ref, "pr"))

@@ -14,7 +14,20 @@ import numpy as np
 import pytest
 
 from conftest import run_forced_four_devices
-from repro.core.dist import ExchangeStats, partition_edges
+from repro.core.dist import ExchangeStats, partition_edges, vertex_mesh
+
+
+def test_vertex_mesh_is_one_auto_axis_over_the_devices():
+    """JAX 0.9's make_mesh defaults to Explicit axes, under which the
+    sharded knn program does not type-check; the engine's meshes are
+    Auto."""
+    import jax
+    from jax.sharding import AxisType
+    mesh = vertex_mesh()
+    assert mesh.axis_names == ("data",)
+    assert mesh.axis_types == (AxisType.Auto,)
+    assert mesh.devices.size == jax.device_count()
+    assert vertex_mesh(1).devices.size == 1
 
 
 def _run_forced_four_devices(prog: str, timeout: int = 600):
@@ -136,14 +149,14 @@ def test_hot_prefix_exact_and_saves_bytes_four_shards():
         from repro.core.baselines import dbg_order
         from repro.core.dist import (ExchangeStats, make_distributed_bfs,
                                      make_distributed_cc,
-                                     make_distributed_sssp)
+                                     make_distributed_sssp, vertex_mesh)
         from repro.core.generators import powerlaw_community
 
         g0 = powerlaw_community(2000, avg_degree=8.0, seed=3)
         perm = np.asarray(dbg_order(g0))
         g = g0.apply_permutation(perm)      # hubs packed into the prefix
         inv = np.empty_like(perm); inv[perm] = np.arange(len(perm))
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = vertex_mesh(4)
         ga = to_device(g, canonical_ids=inv)
         srcs = np.array([5, 321, 1500])
 
@@ -192,11 +205,11 @@ def test_distributed_pagerank_parity_four_shards():
         assert jax.device_count() == 4, jax.devices()
         from repro.algos.graph_arrays import to_device
         from repro.algos.kernels import pagerank
-        from repro.core.dist import make_distributed_pagerank
+        from repro.core.dist import make_distributed_pagerank, vertex_mesh
         from repro.core.generators import powerlaw_community
 
         g = powerlaw_community(2000, avg_degree=8.0, seed=3)
-        mesh = jax.make_mesh((4,), ("data",))
+        mesh = vertex_mesh(4)
         run, _ = make_distributed_pagerank(g, mesh, axis="data",
                                            num_iters=20)
         got = np.asarray(run())

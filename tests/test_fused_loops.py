@@ -29,7 +29,6 @@ from __future__ import annotations
 
 import os
 
-import jax
 import numpy as np
 import pytest
 
@@ -40,7 +39,7 @@ from repro.core.baselines import (bc_baseline, bfs_baseline, cc_baseline,
 from repro.core.dist import (ExchangeStats, make_distributed_bc,
                              make_distributed_bfs, make_distributed_cc,
                              make_distributed_pagerank,
-                             make_distributed_sssp)
+                             make_distributed_sssp, vertex_mesh)
 from repro.core.generators import powerlaw_community
 from repro.engine import BatchedExecutor, EngineSession
 
@@ -58,8 +57,7 @@ def fused_graph():
 
 @pytest.fixture(scope="module")
 def mesh():
-    n = jax.device_count()
-    return jax.make_mesh((n,), ("data",))
+    return vertex_mesh()
 
 
 def _pair(factory, mesh, **kw):
@@ -262,8 +260,7 @@ def test_fused_random_graphs_match_oracles_with_step_bound():
 
     from test_properties import graphs
 
-    n_dev = jax.device_count()
-    mesh = jax.make_mesh((n_dev,), ("data",))
+    mesh = vertex_mesh()
 
     @settings(max_examples=10, deadline=None)
     @given(g=graphs(max_v=40, max_e=128),

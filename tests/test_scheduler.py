@@ -138,6 +138,26 @@ def test_max_batch_sources_chunks_in_order(plc_graph):
     assert idx == sorted(idx)  # FIFO within equal priority
 
 
+def test_device_memory_caps_coalesced_batches(plc_graph, monkeypatch):
+    """A burst coalesces only up to the batch the device has memory for.
+    The CPU reports no memory limit, so there the burst is one launch;
+    under a device cap of 4 the same 12 sources take 3 launches and
+    every row is still its own source's answer."""
+    from repro.core.baselines import bfs_baseline
+    session = _session()
+    gid = session.register(plc_graph, expected_queries=256)
+    assert session._source_cap(session.registry.get(gid), "bfs") is None
+    monkeypatch.setattr(session.executor.single, "source_cap",
+                        lambda handle, kernel: 4)
+    futs = [session.enqueue(gid, "bfs", [s]) for s in range(12)]
+    session.flush()
+    assert session.scheduler.launches == 3
+    assert {f.telemetry["launch_batch_sources"] for f in futs} == {4}
+    for s, f in enumerate(futs):
+        np.testing.assert_array_equal(f.result()[0],
+                                      bfs_baseline(plc_graph, s))
+
+
 def test_global_requests_dedup_into_one_run(plc_graph):
     session = _session()
     gid = session.register(plc_graph, expected_queries=256)

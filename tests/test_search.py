@@ -279,7 +279,8 @@ def test_random_clustered_corpora_property():
     pytest.importorskip("hypothesis")
     from hypothesis import given, settings, strategies as st
 
-    @settings(max_examples=5, deadline=None)
+    @settings(max_examples=5, deadline=None, derandomize=True,
+              database=None)
     @given(seed=st.integers(0, 2**16), n=st.integers(40, 90),
            dim=st.sampled_from([3, 6]), clusters=st.integers(2, 5))
     def check(seed, n, dim, clusters):
