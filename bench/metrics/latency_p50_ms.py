@@ -1,0 +1,9 @@
+"""Median of enqueue -> ``QueryFuture.result()`` returned, over every
+request of the window (host clock)."""
+import numpy as np
+
+
+def read(ctx):
+    if not ctx.latencies_s:
+        return None
+    return 1e3 * float(np.percentile(ctx.latencies_s, 50))

@@ -1,0 +1,8 @@
+"""SSSP launches' share of the HBM roofline: the least bytes any SSSP of
+the window's launches must move (``bench/bytes/sssp.py``) at the chip's
+peak bandwidth, over the kernel program's device time in the trace."""
+from bench.metrics_roofline import roofline_pct
+
+
+def read(ctx):
+    return roofline_pct(ctx, "sssp")

@@ -1,0 +1,196 @@
+"""BENCHMARK.json against the benchmark contract, and the
+harness's lookups by name."""
+from __future__ import annotations
+
+import json
+import os
+import pathlib
+import re
+import subprocess
+import sys
+
+import pytest
+
+from bench import harness
+from bench.conftest import ROOT, copy_benchmark
+
+BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+CELLS = [w["name"] for w in BENCH["workloads"]]
+KEYS = {"config": {"name", "source", "file", "reduced", "why"},
+        "workload": {"name", "config", "traffic", "chips", "why"},
+        "end_to_end": {"name", "unit", "better", "bound", "source"},
+        "per_layer": {"name", "unit", "better", "source", "layer", "moves"}}
+
+
+def _line(text: str) -> bool:
+    return 1 <= len(text) <= 200 and "\n" not in text and "\t" not in text
+
+
+def test_top_level_keys_and_size():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    assert len((ROOT / "BENCHMARK.json").read_bytes()) <= 64 * 1024
+    assert BENCH["paths"] == ["bench"]
+    assert BENCH["command"][1] == "bench/run.py"
+    assert all(_line(w) for w in BENCH["command"])
+
+
+def test_names_units_and_one_line_fields():
+    for kind, items in (("config", BENCH["configs"]),
+                        ("workload", BENCH["workloads"]),
+                        ("end_to_end", BENCH["end_to_end"]),
+                        ("per_layer", BENCH["per_layer"])):
+        names = [i["name"] for i in items]
+        assert len(names) == len(set(names)), kind
+        for item in items:
+            assert set(item) - {"workloads"} == KEYS[kind], item["name"]
+            assert NAME.match(item["name"]), item["name"]
+            if "unit" in item:
+                assert UNIT.match(item["unit"]), item["unit"]
+                assert item["better"] in ("lower", "higher")
+            for field in ("why", "layer", "source"):
+                if field in item and kind in ("config", "workload",
+                                              "per_layer"):
+                    assert _line(item[field]), (item["name"], field)
+    metric_names = [m["name"] for m in BENCH["end_to_end"] + BENCH["per_layer"]]
+    assert len(metric_names) == len(set(metric_names))
+
+
+def test_cells_metrics_and_bounds():
+    configs = {c["name"] for c in BENCH["configs"]}
+    e2e = {m["name"] for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e
+    for w in BENCH["workloads"]:
+        assert w["config"] in configs and w["chips"] in (1, 4)
+        assert NAME.match(w["traffic"])
+    assert {w["config"] for w in BENCH["workloads"]} == configs
+    for m in BENCH["end_to_end"]:
+        assert m["source"] in ("host_clock", "device_trace")
+        assert 0.01 <= m["bound"] <= 0.25
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in e2e
+        assert set(m.get("workloads", CELLS)) <= set(CELLS)
+        if "roofline" in m["name"]:
+            assert m["name"].endswith("_roofline") and m["unit"] == "%"
+    layers = {m["layer"] for m in BENCH["per_layer"]}
+    assert all(_line(layer) for layer in layers)
+
+
+def test_run_seconds_fit_a_full_check():
+    rs = BENCH["run_seconds"]
+    assert isinstance(rs, int) and 1 <= rs <= 51
+    # 24 cells: 2 + 14 runs each, run_seconds + 60 per run, 2 x 90 s of
+    # compile per cell, 1200 s spare, within 43200 s
+    assert (2 + 14 * 24) * (rs + 60) + 24 * 180 + 1200 <= 43200
+
+
+def test_configs_state_their_cuts():
+    for c in BENCH["configs"]:
+        cfg = json.loads((ROOT / c["file"]).read_text())
+        assert c["file"].startswith("bench/configs/")
+        assert cfg["name"] == c["name"] and cfg["source"] == c["source"]
+        assert cfg["reduced"] == c["reduced"]
+        for key in c["reduced"]:
+            assert key in cfg and not key.endswith(("_dim", "_rank"))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_file_of_a_cell_is_found_by_name(cell):
+    spec = harness.resolve(ROOT, cell)
+    for path in (spec.generator, spec.reference, spec.bytes_model):
+        assert path.is_file(), path
+    names = {m["name"] for m in spec.end_to_end + spec.per_layer}
+    for name in names:
+        assert (spec.bench_dir / "metrics" / f"{name}.py").is_file()
+    assert {m["name"] for m in spec.end_to_end} == {
+        m["name"] for m in BENCH["end_to_end"]}
+    assert spec.per_layer
+
+
+def test_a_new_traffic_file_and_cell_need_no_edit(tmp_path):
+    root = copy_benchmark(tmp_path)
+    mix = json.loads((root / "bench/traffic/bfs.burst4.json").read_text())
+    mix["burst"] = 8
+    (root / "bench/traffic/bfs.burst8.json").write_text(json.dumps(mix))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": "grid100.bfs.burst8",
+                               "config": "pbbs-3dgrid100",
+                               "traffic": "bfs.burst8", "chips": 1,
+                               "why": "a wider burst on the same grid"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    spec = harness.resolve(root, "grid100.bfs.burst8")
+    assert spec.traffic["burst"] == 8
+    assert spec.config["name"] == "pbbs-3dgrid100"
+    with pytest.raises(harness.CellError):
+        harness.resolve(root, "grid100.bfs.burst16")
+
+
+def test_a_new_configuration_and_metric_need_no_edit(tmp_path):
+    root = copy_benchmark(tmp_path)
+    cfg = json.loads((root / "bench/configs/pbbs-3dgrid100.json").read_text())
+    cfg.update(name="pbbs-2dgrid", dims=2)
+    (root / "bench/configs/pbbs-2dgrid.json").write_text(json.dumps(cfg))
+    (root / "bench/metrics/bursts_per_s.py").write_text(
+        "def read(ctx):\n    return ctx.answered / 4 / ctx.window_s\n")
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["configs"].append({**bench["configs"][-1], "name": "pbbs-2dgrid",
+                             "file": "bench/configs/pbbs-2dgrid.json"})
+    bench["workloads"].append({"name": "grid2d.bfs.burst4",
+                               "config": "pbbs-2dgrid",
+                               "traffic": "bfs.burst4", "chips": 1,
+                               "why": "the same burst on a 2-D torus"})
+    bench["per_layer"].append({"name": "bursts_per_s", "unit": "1/s",
+                               "better": "higher", "source": "host_clock",
+                               "layer": "request plane", "moves":
+                               "answers_per_s",
+                               "workloads": ["grid2d.bfs.burst4"]})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+    spec = harness.resolve(root, "grid2d.bfs.burst4")
+    assert spec.config["dims"] == 2
+    v, e = harness.load_module(spec.generator).sizes(spec.config)
+    assert (v, e) == (1000 * 1000, 4 * 1000 * 1000)
+    extra = [m for m in spec.per_layer if m["name"] == "bursts_per_s"]
+    ctx = type("Ctx", (), {"answered": 80, "window_s": 2.0})()
+    assert harness.read_metrics(extra, ctx, spec.bench_dir) == {
+        "bursts_per_s": {"value": 10.0, "unit": "1/s"}}
+    assert "bursts_per_s" not in {
+        m["name"] for m in harness.resolve(root, CELLS[0]).per_layer}
+
+
+def test_a_traffic_key_the_harness_does_not_drive_is_refused(tmp_path):
+    root = copy_benchmark(tmp_path)
+    path = root / "bench/traffic/bfs.burst4.json"
+    mix = json.loads(path.read_text())
+    path.write_text(json.dumps({**mix, "loop": "open", "clients": 4}))
+    with pytest.raises(harness.CellError, match="clients"):
+        harness.resolve(root, "grid100.bfs.burst4")
+
+
+def test_unknown_device_kind_is_an_error():
+    assert harness.peaks_for("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
+    with pytest.raises(harness.CellError):
+        harness.peaks_for("TPU v9 imaginary")
+
+
+def _run(cwd: pathlib.Path) -> subprocess.CompletedProcess:
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    env.pop("PYTHONPATH", None)
+    return subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", CELLS[0], "--seed",
+         "3000000001", "--seconds", "1", "--trace", "0"],
+        cwd=cwd, env=env, capture_output=True, text=True, timeout=300)
+
+
+def test_run_refuses_a_cpu_and_prints_no_result():
+    proc = _run(ROOT)
+    assert proc.returncode != 0
+    assert "needs 1 TPU chip" in proc.stderr
+    assert not any(line.startswith("{") for line in proc.stdout.splitlines())
+
+
+def test_run_refuses_without_the_program(tmp_path):
+    proc = _run(copy_benchmark(tmp_path))
+    assert proc.returncode != 0
+    assert not any(line.startswith("{") for line in proc.stdout.splitlines())
