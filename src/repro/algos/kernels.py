@@ -37,9 +37,9 @@ def _seg_min(vals, segs, n):
 
 
 # ---------------------------------------------------------------------- BFS
-@jax.jit
-def bfs(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
-    """Level-synchronous BFS (push). Returns depth (V,), -1 unreached."""
+def _bfs_levels(g: GraphArrays, source: jnp.ndarray
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`bfs` plus the level loop's trip count (eccentricity + 1)."""
     n = g.num_vertices
     depth0 = jnp.full((n,), -1, jnp.int32).at[source].set(0)
     front0 = jnp.zeros((n,), jnp.bool_).at[source].set(True)
@@ -52,16 +52,25 @@ def bfs(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
         depth, front, level = state
         # gather(prop, src) over the edge array: the hot access the paper
         # optimizes — property reads follow g.indices / g.src layout.
-        active = front[g.src]
-        if g.edge_valid is not None:
-            active &= g.edge_valid
-        touched = _seg_max(active, g.indices, n)
+        with jax.named_scope("bfs_frontier_gather"):
+            active = front[g.src]
+            if g.edge_valid is not None:
+                active &= g.edge_valid
+        with jax.named_scope("bfs_segment_scatter"):
+            touched = _seg_max(active, g.indices, n)
         new = touched & (depth < 0)
         depth = jnp.where(new, level + 1, depth)
         return depth, new, level + 1
 
-    depth, _, _ = lax.while_loop(cond, body, (depth0, front0, jnp.int32(0)))
-    return depth
+    depth, _, level = lax.while_loop(cond, body,
+                                     (depth0, front0, jnp.int32(0)))
+    return depth, level
+
+
+@jax.jit
+def bfs(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
+    """Level-synchronous BFS (push). Returns depth (V,), -1 unreached."""
+    return _bfs_levels(g, source)[0]
 
 
 # ----------------------------------------------------------------- PageRank
@@ -234,19 +243,21 @@ def cc_shiloach_vishkin(g: GraphArrays) -> jnp.ndarray:
 
 
 # -------------------------------------------------------- SSSP (Bellman-Ford)
-@jax.jit
-def sssp(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
-    """Bellman-Ford with edge-parallel relaxation (paper's SSSP)."""
+def _sssp_rounds(g: GraphArrays, source: jnp.ndarray
+                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`sssp` plus the relaxation loop's trip count."""
     n = g.num_vertices
     dist0 = jnp.full((n,), INF_I32).at[source].set(0)
 
     def body(state):
         dist, _, it = state
-        du = dist[g.src]
-        cand = jnp.where(du == INF_I32, INF_I32, du + g.weights)
-        if g.edge_valid is not None:
-            cand = jnp.where(g.edge_valid, cand, INF_I32)
-        relaxed = _seg_min(cand, g.indices, n)
+        with jax.named_scope("sssp_candidate_gather"):
+            du = dist[g.src]
+            cand = jnp.where(du == INF_I32, INF_I32, du + g.weights)
+            if g.edge_valid is not None:
+                cand = jnp.where(g.edge_valid, cand, INF_I32)
+        with jax.named_scope("sssp_segment_scatter"):
+            relaxed = _seg_min(cand, g.indices, n)
         new = jnp.minimum(dist, relaxed)
         return new, (new != dist).any(), it + 1
 
@@ -254,8 +265,15 @@ def sssp(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
         _, changed, it = state
         return changed & (it < n)
 
-    dist, _, _ = lax.while_loop(cond, body, (dist0, jnp.bool_(True), jnp.int32(0)))
-    return dist
+    dist, _, rounds = lax.while_loop(cond, body,
+                                     (dist0, jnp.bool_(True), jnp.int32(0)))
+    return dist, rounds
+
+
+@jax.jit
+def sssp(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
+    """Bellman-Ford with edge-parallel relaxation (paper's SSSP)."""
+    return _sssp_rounds(g, source)[0]
 
 
 # -------------------------------------------- Betweenness Centrality (Brandes)
@@ -423,6 +441,24 @@ def bfs_multi(g: GraphArrays, sources: jnp.ndarray) -> jnp.ndarray:
 def sssp_multi(g: GraphArrays, sources: jnp.ndarray) -> jnp.ndarray:
     """Batched Bellman-Ford: (S,) sources -> (S, V) distance rows."""
     return jax.vmap(sssp, in_axes=(None, 0))(g, sources)
+
+
+@jax.jit
+def bfs_multi_steps(g: GraphArrays, sources: jnp.ndarray
+                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`bfs_multi` plus each lane's level-loop trip count (S,) int32 —
+    the program the serving engine compiles, so that it can count the
+    relaxation steps each launch ran (a converged lane's carry freezes,
+    so its count is its own, and the launch ran the lanes' maximum)."""
+    return jax.vmap(_bfs_levels, in_axes=(None, 0))(g, sources)
+
+
+@jax.jit
+def sssp_multi_steps(g: GraphArrays, sources: jnp.ndarray
+                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """`sssp_multi` plus each lane's relaxation-round count (S,) int32,
+    as `bfs_multi_steps` counts levels."""
+    return jax.vmap(_sssp_rounds, in_axes=(None, 0))(g, sources)
 
 
 @jax.jit

@@ -62,9 +62,11 @@ VECTOR_SOURCE = ("knn",)
 # to *attribute* compiles to serving traffic (hit/miss telemetry).
 # knn is the exception: its static beam/step knobs force the per-key
 # jit wrapper pattern (_run_knn), mirroring the pr@spmv path.
+# An entry returns its result, or ``(rows, trips)`` where it also reports
+# each lane's loop trip count (the step counters in `run_arrays`).
 _FNS = {
-    "bfs": K.bfs_multi,
-    "sssp": K.sssp_multi,
+    "bfs": K.bfs_multi_steps,
+    "sssp": K.sssp_multi_steps,
     "bc": K.bc_multi,
     "pr": K.pagerank,
     "cc": K.cc_labelprop,
@@ -394,6 +396,10 @@ class SingleDeviceBackend:
             "engine_cache_evictions_total",
             "LRU executable evictions", backend=self.name)
         self._bucket_counts: dict[tuple[int, int], int] = {}
+        # {"steps", "lanes"} of the last launch whose program counted
+        # its loop trips (None otherwise); the session puts them on the
+        # launch span
+        self.last_run_steps: dict | None = None
 
     @property
     def cache_hits(self) -> int:
@@ -506,6 +512,7 @@ class SingleDeviceBackend:
                    sources=None) -> jnp.ndarray:
         """Execute against raw device arrays (no real-prefix slicing)."""
         build_kernel(kernel)  # unknown kernel: raise before anything counts
+        self.last_run_steps = None
         if kernel in GLOBAL:
             fn = self._compiled(kernel, ga)
             self._counters["queries"].inc()
@@ -519,8 +526,35 @@ class SingleDeviceBackend:
         self._counters["dispatches"].inc()
         self._counters["sources"].inc(real)
         out = fn(ga, jnp.asarray(padded))
+        trips = None
+        if isinstance(out, tuple):       # rows and each lane's trip count
+            out, trips = out
+            # queued now, the copy lands as the program ends instead of
+            # costing a host round trip of its own after the sync
+            trips.copy_to_host_async()
         with self._span("device_sync", kernel=kernel):
-            return jax.block_until_ready(out)[:real]
+            rows = jax.block_until_ready(out)[:real]
+        if trips is not None:
+            self._count_steps(kernel, np.asarray(trips), real)
+        return rows
+
+    def _count_steps(self, kernel: str, trips: np.ndarray,
+                     real: int) -> None:
+        """Step and lane counters of one launch from its lanes' loop trip
+        counts: the launch ran ``max(trips)`` steps over every padded
+        lane, of which the real lanes needed ``sum(trips[:real])``."""
+        steps, lanes = int(trips.max()), len(trips)
+        m = self.metrics
+        m.counter("engine_kernel_steps_total",
+                  "loop steps the kernel programs ran (per launch, the "
+                  "max over its lanes)", kernel=kernel).inc(steps)
+        m.counter("engine_lane_steps_total",
+                  "loop steps the real lanes needed",
+                  kernel=kernel).inc(int(trips[:real].sum()))
+        m.counter("engine_lane_slots_total",
+                  "loop steps x padded lanes per launch",
+                  kernel=kernel).inc(steps * lanes)
+        self.last_run_steps = {"steps": steps, "lanes": lanes}
 
     def _run_pr_spmv(self, handle: GraphHandle) -> jnp.ndarray:
         """PR with the relaxation on the Pallas CSR kernel (still one
@@ -571,6 +605,7 @@ class SingleDeviceBackend:
 
     def run(self, handle: GraphHandle, kernel: str,
             sources=None) -> jnp.ndarray:
+        self.last_run_steps = None
         if kernel in VECTOR_SOURCE:
             # knn returns (ids, visits), already sliced to real shapes
             return self._run_knn(handle, sources)

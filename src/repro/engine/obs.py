@@ -16,8 +16,9 @@ writes into:
 
 * **Trace spans** (`Tracer`) — Chrome-trace-event JSON (load the exported
   file in https://ui.perfetto.dev or ``chrome://tracing``). Engine-side
-  phases (flush, coalesce, translate, launch, device_sync, per-step
-  sharded ``exchange``, reorder, redecide) land on the engine track;
+  phases (flush, coalesce, translate, launch, device_sync, d2h,
+  unpermute, cache_fill, slice_out, per-step sharded ``exchange``,
+  reorder, redecide) land on the engine track;
   each request gets its own track carrying ``enqueue`` → ``queue_wait``
   → ``serve``, tied together by the ``trace_id`` every `QueryFuture`
   carries. Events are buffered (bounded, drop-oldest-never: excess
@@ -25,10 +26,12 @@ writes into:
 
 * **Profiling hooks** (`ProfilerHook`) — an optional ``jax.profiler``
   integration enabled per-session: ``start()``/``stop()`` bracket a
-  device-level trace into a log dir, and ``step(name)`` wraps each
-  launch in a `StepTraceAnnotation` so engine launches line up with XLA
-  events in the profiler UI. Fully inert (and import-error-proof) when
-  no log dir is configured.
+  device-level trace into a log dir. While it is active, every tracer
+  span is mirrored as a `TraceAnnotation` of the same name (the
+  ``launch`` span as the `StepTraceAnnotation` of its launch number), so
+  the engine's phases land on the profiler's host plane, on the clock of
+  the device ops. Fully inert (and import-error-proof) when no log dir
+  is configured.
 
 * **Clocks** (`Clock` / `ManualClock`) — the single injectable monotonic
   time source. The session owns one and the scheduler/tracer read it,
@@ -401,6 +404,9 @@ class Tracer:
     def __init__(self, clock: Clock | None = None,
                  max_events: int = 200_000, pid: int = 1):
         self.clock = clock or Clock()
+        # spans are mirrored into the profiler trace while this hook is
+        # active (a session hands the tracer its own hook)
+        self.profiler = ProfilerHook()
         self.max_events = max_events
         self.pid = pid
         self.events: list[dict] = []
@@ -424,14 +430,26 @@ class Tracer:
 
     # ------------------------------------------------------------- emitters
     @contextlib.contextmanager
-    def span(self, name: str, tid: int = ENGINE_TID, **args):
+    def span(self, name: str, tid: int = ENGINE_TID,
+             step_num: int | None = None, **args):
         """Complete event covering the ``with`` block; yields the args
-        dict so facts discovered inside the block can be attached."""
+        dict so facts discovered inside the block can be attached.
+
+        While ``self.profiler`` is active the block also runs inside a
+        profiler annotation of the same name — a `StepTraceAnnotation`
+        numbered ``step_num`` where one is given — so the span shows on
+        the device trace's clock too."""
+        mirror = (self.profiler.annotation(name, step_num)
+                  if self.profiler.active else None)
+        if mirror is not None:
+            mirror.__enter__()
         start = self.clock.now()
         try:
             yield args
         finally:
             self.emit(name, start, self.clock.now(), tid=tid, args=args)
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
 
     def emit(self, name: str, start: float, end: float,
              tid: int = ENGINE_TID, args: dict | None = None) -> None:
@@ -514,11 +532,13 @@ class ProfilerHook:
 
     ``start()``/``stop()`` bracket a device-level profiler trace written
     to ``log_dir`` (open with TensorBoard's profile plugin or
-    ui.perfetto.dev); ``step(name)`` wraps one engine launch in a
-    `StepTraceAnnotation` so scheduler launches are attributable inside
-    the XLA timeline. Everything is a no-op when unconfigured, and any
-    profiler failure (unsupported platform, double-start) is recorded in
-    ``error`` instead of failing the serving path.
+    ui.perfetto.dev). The Python tracer is off: while the hook is
+    active, the `Tracer` it is attached to mirrors each span as an
+    ``annotation`` instead, so the host side of the trace is the
+    engine's own span vocabulary. Everything is a no-op when
+    unconfigured, and any profiler failure (unsupported platform,
+    double-start) is recorded in ``error`` instead of failing the
+    serving path.
     """
 
     def __init__(self, log_dir: str | None = None):
@@ -535,7 +555,9 @@ class ProfilerHook:
             return False
         try:
             import jax
-            jax.profiler.start_trace(self.log_dir)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            jax.profiler.start_trace(self.log_dir, profiler_options=options)
             self.active = True
         except Exception as exc:  # profiling must never fail serving
             self.error = f"start_trace: {exc}"
@@ -552,16 +574,18 @@ class ProfilerHook:
         self.active = False
         return True
 
-    def step(self, name: str, step_num: int = 0):
-        """Context manager around one launch (inert unless active)."""
-        if not self.active:
-            return contextlib.nullcontext()
+    def annotation(self, name: str, step_num: int | None = None):
+        """Profiler annotation mirroring one tracer span: a
+        `StepTraceAnnotation` where the span numbers a step (a launch),
+        else a `TraceAnnotation`."""
         try:
             import jax
-            return jax.profiler.StepTraceAnnotation(name,
-                                                    step_num=step_num)
+            if step_num is not None:
+                return jax.profiler.StepTraceAnnotation(name,
+                                                        step_num=step_num)
+            return jax.profiler.TraceAnnotation(name)
         except Exception as exc:
-            self.error = f"step: {exc}"
+            self.error = f"annotation: {exc}"
             return contextlib.nullcontext()
 
 

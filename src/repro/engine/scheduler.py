@@ -770,16 +770,18 @@ class MicroBatchScheduler:
             session.policy.observe_batch_sources(len(missing))
             self._c_launches.inc()
             hot = entry.hot_prefix_len
-            for i, key in enumerate(missing_keys):
-                # copy: a slice view would pin the whole (S, V) launch
-                # array for as long as any one cached row is retained
-                row = out[i].copy()
-                rows[key] = row
-                # knn rows are keyed by content digest, not vertex id, so
-                # GRASP pinning (a vertex-prefix rule) never applies
-                pinned = (not is_vec and hot > 0
-                          and int(entry.perm[key]) < hot)
-                cache.put(gid, gen, kernel, key, row, pinned=pinned)
+            with session.tracer.span("cache_fill", graph_id=gid,
+                                     kernel=kernel, rows=len(missing_keys)):
+                for i, key in enumerate(missing_keys):
+                    # copy: a slice view would pin the whole (S, V) launch
+                    # array for as long as any one cached row is retained
+                    row = out[i].copy()
+                    rows[key] = row
+                    # knn rows are keyed by content digest, not vertex id,
+                    # so GRASP pinning (a vertex-prefix rule) never applies
+                    pinned = (not is_vec and hot > 0
+                              and int(entry.perm[key]) < hot)
+                    cache.put(gid, gen, kernel, key, row, pinned=pinned)
         else:
             # every row came from memory — the whole chunk serves with no
             # device work at all; make that visible on the engine track

@@ -195,6 +195,7 @@ class EngineSession:
         self.tracer = tracer or Tracer(clock=self.clock)
         self.executor.tracer = self.tracer
         self.profiler = ProfilerHook(profiler_dir)
+        self.tracer.profiler = self.profiler
         m = self.metrics_registry
         self._c_registered = m.counter("engine_graphs_registered_total",
                                        "graphs registered with the session")
@@ -889,12 +890,11 @@ class EngineSession:
         # first use per kernel instead — annotated by the backend)
         misses0 = self.executor.single.cache_misses
         t0 = self.clock.now()
-        with tracer.span("launch", graph_id=entry.graph_id, kernel=kernel,
+        with tracer.span("launch", step_num=self.scheduler.launches,
+                         graph_id=entry.graph_id, kernel=kernel,
                          backend=entry.backend) as span_args:
-            with self.profiler.step(kernel,
-                                    step_num=self.scheduler.launches):
-                out = self.executor.run(entry.handle, kernel,
-                                        served_sources)
+            out = self.executor.run(entry.handle, kernel, served_sources)
+            with tracer.span("d2h", kernel=kernel):
                 if is_vec:
                     ids, visits = np.asarray(out[0]), np.asarray(out[1])
                 else:
@@ -902,30 +902,35 @@ class EngineSession:
             if entry.backend == "single":
                 hit = self.executor.single.cache_misses == misses0
                 span_args["compile"] = "cache_hit" if hit else "compile"
+                span_args.update(self.executor.single.last_run_steps or {})
         wall = self.clock.now() - t0
         self.metrics_registry.histogram(
-            "engine_launch_wall_seconds", "device wall per launch",
+            "engine_launch_wall_seconds",
+            "dispatch + device wait + device-to-host copy per launch "
+            "(host clock)",
             kernel=kernel, backend=entry.backend).observe(wall)
-        if is_vec:
-            # visit counts arrive per served vertex; fold them back to
-            # original ids and into the registry's EWMA hotness estimate
-            # (the telemetry refresh_hotness folds into the layout)
-            self.registry.note_visits(entry.graph_id,
-                                      np.asarray(visits)[entry.perm],
-                                      num_queries=len(served_sources))
-            # neighbor ids are served ids (-1 = unfilled beam slot; guard
-            # the gather — a raw inv_perm[-1] would alias the last vertex)
-            result = np.where(ids >= 0,
-                              entry.inv_perm[np.maximum(ids, 0)],
-                              -1).astype(np.int64)
-            return result, wall
         # translate back: result for original vertex v lives at served
         # position perm[v]; component-label *values* (cc/ccsv) are served
         # ids and are canonicalized to min-original-id-per-component so
         # callers never see the internal layout (PR 4 leaked this)
-        result = out[..., entry.perm]
-        if kernel in LABEL_KERNELS:
-            result = canonical_component_labels(result)
+        with tracer.span("unpermute", kernel=kernel):
+            if is_vec:
+                # visit counts arrive per served vertex; neighbor ids are
+                # served ids (-1 = unfilled beam slot; guard the gather —
+                # a raw inv_perm[-1] would alias the last vertex)
+                visits = visits[entry.perm]
+                result = np.where(ids >= 0,
+                                  entry.inv_perm[np.maximum(ids, 0)],
+                                  -1).astype(np.int64)
+            else:
+                result = out[..., entry.perm]
+                if kernel in LABEL_KERNELS:
+                    result = canonical_component_labels(result)
+        if is_vec:
+            # fold the visit counts into the registry's EWMA hotness
+            # estimate (the telemetry refresh_hotness folds into the layout)
+            self.registry.note_visits(entry.graph_id, visits,
+                                      num_queries=len(served_sources))
         return result, wall
 
     def _source_cap(self, entry: GraphEntry, kernel: str) -> int | None:

@@ -84,10 +84,17 @@ def _fits(compiled) -> object:
     return m
 
 
-def _multi_source(fn, kernel: str, sharding, scale: int, batch: int):
+def _multi_source(fn, kernel: str, sharding, scale: int, batch: int,
+                  scopes: tuple[str, ...] = ()):
     v, e = _kron(scale)
     sources = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=sharding)
-    m = _fits(jax.jit(fn).lower(_graph(sharding, v, e), sources).compile())
+    compiled = jax.jit(fn).lower(_graph(sharding, v, e), sources).compile()
+    m = _fits(compiled)
+    # the per-step named scopes survive into the compiled program's op
+    # metadata, which is how a device op of the trace is traced back
+    hlo = compiled.as_text()
+    for scope in scopes:
+        assert f"/{scope}/" in hlo, scope
     # the byte model the scheduler bounds batches with must cover what
     # the compiler actually allocates
     model = launch_bytes(kernel, batch, v, e)
@@ -105,13 +112,16 @@ def _smoke_batch(kernel: str, scale: int) -> int:
 
 
 def test_compile_bfs_multi(one_chip):
-    _multi_source(K.bfs_multi, "bfs", one_chip, SMOKE_SCALE,
-                  _smoke_batch("bfs", SMOKE_SCALE))
+    # the program the engine serves: the rows plus each lane's trip count
+    _multi_source(K.bfs_multi_steps, "bfs", one_chip, SMOKE_SCALE,
+                  _smoke_batch("bfs", SMOKE_SCALE),
+                  ("bfs_frontier_gather", "bfs_segment_scatter"))
 
 
 def test_compile_sssp_multi(one_chip):
-    _multi_source(K.sssp_multi, "sssp", one_chip, SMOKE_SCALE,
-                  _smoke_batch("sssp", SMOKE_SCALE))
+    _multi_source(K.sssp_multi_steps, "sssp", one_chip, SMOKE_SCALE,
+                  _smoke_batch("sssp", SMOKE_SCALE),
+                  ("sssp_candidate_gather", "sssp_segment_scatter"))
 
 
 def test_compile_bc_multi_at_batch_bound(one_chip):
