@@ -39,6 +39,10 @@ class GraphArrays(NamedTuple):
     # exact same XLA programs as before bucketing existed.
     vertex_valid: jnp.ndarray | None = None  # (V,) bool, False = padding
     edge_valid: jnp.ndarray | None = None    # (E,) bool, False = sentinel
+    # (E,) int32 edge weights aligned with the in-CSR, for the pull
+    # relaxation. None (a positional build from the CSR fields alone)
+    # makes the SSSP program derive them from ``weights`` itself.
+    t_weights: jnp.ndarray | None = None
 
     @property
     def num_vertices(self) -> int:
@@ -80,15 +84,18 @@ def to_device(g: Graph, weight_seed: int = 17,
     ``pad_to=(num_v, num_e)`` uploads the graph padded to that bucket
     shape: extra vertices are isolated (degree 0, ``vertex_valid`` False),
     extra edges are self-loops on the last padded vertex (``edge_valid``
-    False, weight 1). Kernels mask them out, so results restricted to the
-    real ``[:V]`` prefix equal the unpadded run. When edges are padded
-    there must be at least one padded vertex to host the sentinels —
-    `engine.backends.bucket_dims` guarantees that.
+    False, weight 1 in both CSR views). Kernels mask them out, so results
+    restricted to the real ``[:V]`` prefix equal the unpadded run. When
+    edges are padded there must be at least one padded vertex to host the
+    sentinels — `engine.backends.bucket_dims` guarantees that.
     """
     t = g.transpose
     src = g.edge_src.astype(np.int64)
     dst = g.indices.astype(np.int64)
     w = edge_weights(src, dst, canonical_ids)
+    # the same hash on the in-CSR's (src, dst) pairs: every arc keeps its
+    # weight in both views, duplicates included
+    t_w = edge_weights(t.indices, t.edge_src, canonical_ids)
     _ = weight_seed  # reserved; hash keeps weights relabel-invariant
 
     n, e = g.num_vertices, g.num_edges
@@ -112,6 +119,7 @@ def to_device(g: Graph, weight_seed: int = 17,
             out_degree=jnp.asarray(g.out_degree, jnp.int32),
             in_degree=jnp.asarray(g.in_degree, jnp.int32),
             weights=jnp.asarray(w, jnp.int32),
+            t_weights=jnp.asarray(t_w, jnp.int32),
         )
 
     sentinel = num_v - 1  # always a padded vertex when sentinel edges exist
@@ -150,4 +158,5 @@ def to_device(g: Graph, weight_seed: int = 17,
         weights=jnp.asarray(pad_e(w, 1)),
         vertex_valid=jnp.asarray(vertex_valid),
         edge_valid=jnp.asarray(edge_valid),
+        t_weights=jnp.asarray(pad_e(t_w, 1)),
     )

@@ -1,9 +1,10 @@
 """The six GAP-style graph kernels in pure JAX (paper §5.1).
 
-Each kernel is edge-parallel (COO segment ops) with `lax.while_loop`
-outer iteration — the JAX-native rendering of the level-synchronous /
-iterative structure the paper's C++ GAPS kernels use. All are `jit`-able;
-vertex property arrays are the reuse-heavy state the paper reorders for.
+Each kernel is edge-parallel (COO segment ops; BFS and SSSP pull over the
+in-CSR with a segmented scan) with `lax.while_loop` outer iteration — the
+JAX-native rendering of the level-synchronous / iterative structure the
+paper's C++ GAPS kernels use. All are `jit`-able; vertex property arrays
+are the reuse-heavy state the paper reorders for.
 
 Bucket padding: when a `GraphArrays` carries ``vertex_valid`` /
 ``edge_valid`` masks (shape-bucketed uploads, see engine/backends.py),
@@ -14,6 +15,8 @@ trace time, so unbucketed serving lowers to the identical XLA program as
 before.
 """
 from __future__ import annotations
+
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -28,49 +31,131 @@ def _seg_sum(vals, segs, n):
     return jax.ops.segment_sum(vals, segs, num_segments=n)
 
 
-def _seg_max(vals, segs, n):
-    return jax.ops.segment_max(vals, segs, num_segments=n)
-
-
 def _seg_min(vals, segs, n):
     return jax.ops.segment_min(vals, segs, num_segments=n)
 
 
+# ------------------------------------------- in-CSR segmented reduction
+#
+# BFS and SSSP pull over the in-CSR: its arcs are sorted by destination,
+# so each vertex's in-arcs form one contiguous run of the edge axis, and
+# the per-step reduction onto destinations is a segmented scan along that
+# axis instead of a scatter onto unsorted ids. The scan doubles: pass k
+# combines x[i] with x[i - 2**k] where both lie in one run, so after
+# ⌈log2 d⌉ passes the last arc of every run of length <= d holds the
+# reduction of the whole run. Every pass is a dense shift and select.
+
+class InRuns(NamedTuple):
+    """The in-CSR's destination runs, as the pull steps read them."""
+    offset: jnp.ndarray  # (E,) int32 position of each in-arc in its run
+    ends: jnp.ndarray    # (V,) int32 each vertex's last in-arc (0 if none)
+    has_in: jnp.ndarray  # (V,) bool, in-degree > 0
+    passes: jnp.ndarray  # () int32 ⌈log2⌉ of the largest real in-degree
+
+
+def _in_runs(g: GraphArrays) -> InRuns:
+    """Loop-invariant run layout of ``g``'s in-CSR. The pass count is
+    taken over real vertices only, so a bucket's sentinel tail (booked to
+    a padded vertex, all masked) does not lengthen the scan; it is a
+    traced value, so one compiled program serves every graph of a
+    bucket."""
+    e = g.num_edges
+    pos = jnp.arange(e, dtype=jnp.int32)
+    first = (pos == 0) | (g.t_dst != jnp.roll(g.t_dst, 1))
+    offset = pos - lax.cummax(jnp.where(first, pos, 0))
+    deg = g.t_indptr[1:] - g.t_indptr[:-1]
+    if g.vertex_valid is not None:
+        deg = jnp.where(g.vertex_valid, deg, 0)
+    longest = jnp.maximum(deg.max(), 1)
+    passes = (32 - lax.clz(longest - 1)).astype(jnp.int32)
+    return InRuns(offset, jnp.maximum(g.t_indptr[1:] - 1, 0),
+                  g.t_indptr[1:] > g.t_indptr[:-1], passes)
+
+
+def _segment_reduce(x: jnp.ndarray, runs: InRuns, op, empty) -> jnp.ndarray:
+    """(..., E) per-in-arc values -> (..., V) ``op``-reduction over each
+    vertex's in-arc run (``empty`` where it has none). ``op`` is
+    idempotent (OR, min), so overlapping windows are harmless."""
+    if x.shape[-1] == 0:                 # no arcs, so every run is empty
+        return jnp.full(x.shape[:-1] + runs.has_in.shape, empty, x.dtype)
+
+    def one_pass(k, x):
+        shift = jnp.left_shift(jnp.int32(1), k)
+        return jnp.where(runs.offset >= shift,
+                         op(x, jnp.roll(x, shift, axis=-1)), x)
+
+    x = lax.fori_loop(0, runs.passes, one_pass, x)
+    return jnp.where(runs.has_in, x[..., runs.ends], empty)
+
+
 # ---------------------------------------------------------------------- BFS
-def _bfs_levels(g: GraphArrays, source: jnp.ndarray
+#
+# The lanes of a multi-source BFS travel as bits: lane l is bit l % 32 of
+# word l // 32, so one level gathers and scans one uint32 per arc and
+# word, whatever the number of lanes up to 32, and the segmented OR is a
+# bitwise OR.
+
+def _lane_bits(num_lanes: int) -> jnp.ndarray:
+    """(S, 1) uint32: each lane's bit within its word."""
+    lane = jnp.arange(num_lanes, dtype=jnp.uint32) % 32
+    return jnp.left_shift(jnp.uint32(1), lane)[:, None]
+
+
+def _pack_lanes(flags: jnp.ndarray) -> jnp.ndarray:
+    """(S, V) bool -> (⌈S/32⌉, V) uint32 lane bits."""
+    s = flags.shape[0]
+    bits = jnp.where(flags, _lane_bits(s), jnp.uint32(0))
+    bits = jnp.pad(bits, ((0, -s % 32), (0, 0)))
+    # the bits of one word are distinct, so their sum is their OR
+    return bits.reshape(-1, 32, bits.shape[-1]).sum(axis=1,
+                                                    dtype=jnp.uint32)
+
+
+def _unpack_lanes(words: jnp.ndarray, num_lanes: int) -> jnp.ndarray:
+    """(⌈S/32⌉, V) uint32 lane bits -> (S, V) bool."""
+    per_lane = jnp.repeat(words, 32, axis=0)[:num_lanes]
+    return (per_lane & _lane_bits(num_lanes)) != 0
+
+
+def _bfs_levels(g: GraphArrays, runs: InRuns, sources: jnp.ndarray
                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """`bfs` plus the level loop's trip count (eccentricity + 1)."""
-    n = g.num_vertices
-    depth0 = jnp.full((n,), -1, jnp.int32).at[source].set(0)
-    front0 = jnp.zeros((n,), jnp.bool_).at[source].set(True)
+    """Level-synchronous BFS from (S,) sources at once -> (S, V) depths
+    and each lane's level-loop trip count (its eccentricity + 1)."""
+    n, s = g.num_vertices, sources.shape[0]
+    start = jnp.arange(n, dtype=jnp.int32)[None, :] == sources[:, None]
+    depth0 = jnp.where(start, 0, -1).astype(jnp.int32)
+    front0 = _pack_lanes(start)
 
     def cond(state):
-        _, front, _ = state
-        return front.any()
+        _, front, _, _ = state
+        return (front != 0).any()
 
     def body(state):
-        depth, front, level = state
-        # gather(prop, src) over the edge array: the hot access the paper
-        # optimizes — property reads follow g.indices / g.src layout.
+        depth, front, seen, level = state
+        # gather(prop, src) over the in-CSR: the hot access the paper
+        # optimizes — property reads follow the g.t_indices layout.
         with jax.named_scope("bfs_frontier_gather"):
-            active = front[g.src]
+            active = front[:, g.t_indices]
             if g.edge_valid is not None:
-                active &= g.edge_valid
-        with jax.named_scope("bfs_segment_scatter"):
-            touched = _seg_max(active, g.indices, n)
-        new = touched & (depth < 0)
-        depth = jnp.where(new, level + 1, depth)
-        return depth, new, level + 1
+                active = jnp.where(g.edge_valid, active, jnp.uint32(0))
+        with jax.named_scope("bfs_segment_reduce"):
+            touched = _segment_reduce(active, runs, jnp.bitwise_or,
+                                      jnp.uint32(0))
+        new = touched & ~seen
+        depth = jnp.where(_unpack_lanes(new, s), level + 1, depth)
+        return depth, new, seen | new, level + 1
 
-    depth, _, level = lax.while_loop(cond, body,
-                                     (depth0, front0, jnp.int32(0)))
-    return depth, level
+    depth, _, _, _ = lax.while_loop(
+        cond, body, (depth0, front0, front0, jnp.int32(0)))
+    # a lane's loop ran while its frontier was not empty: one level past
+    # its deepest vertex
+    return depth, depth.max(axis=1) + 1
 
 
 @jax.jit
 def bfs(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
-    """Level-synchronous BFS (push). Returns depth (V,), -1 unreached."""
-    return _bfs_levels(g, source)[0]
+    """Level-synchronous BFS (pull). Returns depth (V,), -1 unreached."""
+    return _bfs_levels(g, _in_runs(g), jnp.reshape(source, (1,)))[0][0]
 
 
 # ----------------------------------------------------------------- PageRank
@@ -243,8 +328,18 @@ def cc_shiloach_vishkin(g: GraphArrays) -> jnp.ndarray:
 
 
 # -------------------------------------------------------- SSSP (Bellman-Ford)
-def _sssp_rounds(g: GraphArrays, source: jnp.ndarray
-                 ) -> tuple[jnp.ndarray, jnp.ndarray]:
+def _in_weights(g: GraphArrays) -> jnp.ndarray:
+    """Edge weights in in-CSR order. An upload without them derives them:
+    the in-CSR is the out-CSR's arcs stably sorted by destination (sources
+    ascend within each run in both), and sentinel arcs are the tail of
+    both views."""
+    if g.t_weights is not None:
+        return g.t_weights
+    return g.weights[jnp.argsort(g.indices, stable=True)]
+
+
+def _sssp_rounds(g: GraphArrays, runs: InRuns, t_weights: jnp.ndarray,
+                 source: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """`sssp` plus the relaxation loop's trip count."""
     n = g.num_vertices
     dist0 = jnp.full((n,), INF_I32).at[source].set(0)
@@ -252,12 +347,12 @@ def _sssp_rounds(g: GraphArrays, source: jnp.ndarray
     def body(state):
         dist, _, it = state
         with jax.named_scope("sssp_candidate_gather"):
-            du = dist[g.src]
-            cand = jnp.where(du == INF_I32, INF_I32, du + g.weights)
+            du = dist[g.t_indices]
+            cand = jnp.where(du == INF_I32, INF_I32, du + t_weights)
             if g.edge_valid is not None:
                 cand = jnp.where(g.edge_valid, cand, INF_I32)
-        with jax.named_scope("sssp_segment_scatter"):
-            relaxed = _seg_min(cand, g.indices, n)
+        with jax.named_scope("sssp_segment_reduce"):
+            relaxed = _segment_reduce(cand, runs, jnp.minimum, INF_I32)
         new = jnp.minimum(dist, relaxed)
         return new, (new != dist).any(), it + 1
 
@@ -272,8 +367,9 @@ def _sssp_rounds(g: GraphArrays, source: jnp.ndarray
 
 @jax.jit
 def sssp(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
-    """Bellman-Ford with edge-parallel relaxation (paper's SSSP)."""
-    return _sssp_rounds(g, source)[0]
+    """Bellman-Ford with edge-parallel relaxation (paper's SSSP), pulled
+    over the in-CSR."""
+    return _sssp_rounds(g, _in_runs(g), _in_weights(g), source)[0]
 
 
 # -------------------------------------------- Betweenness Centrality (Brandes)
@@ -427,14 +523,15 @@ def knn_search_multi(g: GraphArrays, vectors: jnp.ndarray,
 # ---------------------------------------------- batched multi-source variants
 #
 # The serving engine amortizes one compile over many concurrent queries:
-# sources become a batch axis via `vmap`. The while/fori loops inside the
-# single-source kernels batch cleanly — JAX's while_loop batching rule runs
-# until every lane's predicate clears and select-freezes converged lanes.
+# sources become a batch axis, BFS's as bits of its frontier words (above)
+# and the others' via `vmap`. The while/fori loops inside the single-source
+# kernels batch cleanly — JAX's while_loop batching rule runs until every
+# lane's predicate clears and select-freezes converged lanes.
 
 @jax.jit
 def bfs_multi(g: GraphArrays, sources: jnp.ndarray) -> jnp.ndarray:
     """Batched BFS: (S,) sources -> (S, V) depth rows, -1 unreached."""
-    return jax.vmap(bfs, in_axes=(None, 0))(g, sources)
+    return _bfs_levels(g, _in_runs(g), sources)[0]
 
 
 @jax.jit
@@ -445,20 +542,26 @@ def sssp_multi(g: GraphArrays, sources: jnp.ndarray) -> jnp.ndarray:
 
 @jax.jit
 def bfs_multi_steps(g: GraphArrays, sources: jnp.ndarray
-                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """`bfs_multi` plus each lane's level-loop trip count (S,) int32 —
-    the program the serving engine compiles, so that it can count the
-    relaxation steps each launch ran (a converged lane's carry freezes,
-    so its count is its own, and the launch ran the lanes' maximum)."""
-    return jax.vmap(_bfs_levels, in_axes=(None, 0))(g, sources)
+                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """`bfs_multi` plus each lane's level-loop trip count (S,) int32 and
+    the segmented reduction's passes per level () int32 — the program the
+    serving engine compiles, so that it can count the relaxation steps
+    each launch ran (a lane's count is its own, one level past its
+    deepest vertex, and the launch ran the lanes' maximum)."""
+    runs = _in_runs(g)
+    rows, trips = _bfs_levels(g, runs, sources)
+    return rows, trips, runs.passes
 
 
 @jax.jit
 def sssp_multi_steps(g: GraphArrays, sources: jnp.ndarray
-                     ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """`sssp_multi` plus each lane's relaxation-round count (S,) int32,
-    as `bfs_multi_steps` counts levels."""
-    return jax.vmap(_sssp_rounds, in_axes=(None, 0))(g, sources)
+                     ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """`sssp_multi` plus each lane's relaxation-round count (S,) int32 and
+    the passes per round, as `bfs_multi_steps` counts levels."""
+    runs = _in_runs(g)
+    rows, trips = jax.vmap(_sssp_rounds, in_axes=(None, None, None, 0))(
+        g, runs, _in_weights(g), sources)
+    return rows, trips, runs.passes
 
 
 @jax.jit

@@ -62,8 +62,9 @@ VECTOR_SOURCE = ("knn",)
 # to *attribute* compiles to serving traffic (hit/miss telemetry).
 # knn is the exception: its static beam/step knobs force the per-key
 # jit wrapper pattern (_run_knn), mirroring the pr@spmv path.
-# An entry returns its result, or ``(rows, trips)`` where it also reports
-# each lane's loop trip count (the step counters in `run_arrays`).
+# An entry returns its result, or ``(rows, trips, passes)`` where it also
+# reports each lane's loop trip count and the passes of its segmented
+# reduction per step (the step counters in `run_arrays`).
 _FNS = {
     "bfs": K.bfs_multi_steps,
     "sssp": K.sssp_multi_steps,
@@ -135,9 +136,9 @@ def estimate_device_bytes(num_vertices: int, num_edges: int,
                           batch_sources: int = 0) -> int:
     """Device footprint of serving one graph (the placement input).
 
-    CSR upload: int32 fields — 2x indptr (V+1), 5x edge-sized (indices,
-    src, t_indices, t_dst, weights), 2x vertex-sized degrees — plus
-    1-byte bool masks.
+    CSR upload: int32 fields — 2x indptr (V+1), 6x edge-sized (indices,
+    src, t_indices, t_dst, weights, t_weights), 2x vertex-sized degrees
+    — plus 1-byte bool masks.
 
     ``batch_sources`` adds the **query state** (placement v2): a
     multi-source launch of S sources holds an (S, V) int32 property
@@ -148,21 +149,22 @@ def estimate_device_bytes(num_vertices: int, num_edges: int,
     graph that fits alone but not under its real traffic's batches is
     placed sharded.
     """
-    return (4 * (2 * (num_vertices + 1) + 5 * num_edges + 2 * num_vertices)
+    return (4 * (2 * (num_vertices + 1) + 6 * num_edges + 2 * num_vertices)
             + num_vertices + num_edges
             + 8 * batch_sources * num_vertices)
 
 
 # Device bytes one more source (or knn query) adds to a vmapped launch,
 # as (bytes per edge, bytes per vertex). The edge term is what the
-# vmapped bodies materialize per lane: BFS its gathered frontier flags,
-# SSSP its int32 relaxation candidates, BC the per-lane tree masks,
-# depths and float gathers of both Brandes passes. Fitted to the TPU
-# compiler's own `memory_analysis()` at Graph500 scale 22 on v5e (BFS
-# 1.1, SSSP 4.0, BC 17.5 bytes per edge per source, plus ~4·E bytes per
-# launch whatever S is), rounded up; tests/test_tpu_compile.py holds the
-# compiler to them.
-_SOURCE_BYTES = {"bfs": (2, 16), "sssp": (5, 16), "bc": (18, 32),
+# vmapped bodies materialize per lane: BFS its gathered frontier flags
+# and their segmented scan, SSSP its int32 relaxation candidates and
+# their scan (a pass reads one buffer and writes another), BC the
+# per-lane tree masks, depths and float gathers of both Brandes passes.
+# Fitted to the TPU compiler's own `memory_analysis()` at Graph500 scale
+# 22 on v5e (BFS 2.1, SSSP 8.0, BC 17.5 bytes per edge per source, plus
+# ~4·E bytes per launch whatever S is), rounded up;
+# tests/test_tpu_compile.py holds the compiler to them.
+_SOURCE_BYTES = {"bfs": (2, 16), "sssp": (8, 16), "bc": (18, 32),
                  "knn": (0, 8)}
 # the S-independent per-launch temporaries, in bytes per edge
 _LAUNCH_BYTES_PER_EDGE = 4
@@ -526,23 +528,26 @@ class SingleDeviceBackend:
         self._counters["dispatches"].inc()
         self._counters["sources"].inc(real)
         out = fn(ga, jnp.asarray(padded))
-        trips = None
-        if isinstance(out, tuple):       # rows and each lane's trip count
-            out, trips = out
-            # queued now, the copy lands as the program ends instead of
-            # costing a host round trip of its own after the sync
+        trips = passes = None
+        if isinstance(out, tuple):       # rows, trip counts, passes
+            out, trips, passes = out
+            # queued now, the copies land as the program ends instead of
+            # costing a host round trip of their own after the sync
             trips.copy_to_host_async()
+            passes.copy_to_host_async()
         with self._span("device_sync", kernel=kernel):
             rows = jax.block_until_ready(out)[:real]
         if trips is not None:
-            self._count_steps(kernel, np.asarray(trips), real)
+            self._count_steps(kernel, np.asarray(trips), real,
+                              int(passes))
         return rows
 
-    def _count_steps(self, kernel: str, trips: np.ndarray,
-                     real: int) -> None:
+    def _count_steps(self, kernel: str, trips: np.ndarray, real: int,
+                     passes: int) -> None:
         """Step and lane counters of one launch from its lanes' loop trip
         counts: the launch ran ``max(trips)`` steps over every padded
-        lane, of which the real lanes needed ``sum(trips[:real])``."""
+        lane, of which the real lanes needed ``sum(trips[:real])``, and
+        each step ran ``passes`` passes of the segmented reduction."""
         steps, lanes = int(trips.max()), len(trips)
         m = self.metrics
         m.counter("engine_kernel_steps_total",
@@ -554,6 +559,10 @@ class SingleDeviceBackend:
         m.counter("engine_lane_slots_total",
                   "loop steps x padded lanes per launch",
                   kernel=kernel).inc(steps * lanes)
+        m.counter("engine_segment_passes_total",
+                  "segmented-reduction passes the kernel programs ran "
+                  "(per launch, passes per step x steps)",
+                  kernel=kernel).inc(passes * steps)
         self.last_run_steps = {"steps": steps, "lanes": lanes}
 
     def _run_pr_spmv(self, handle: GraphHandle) -> jnp.ndarray:

@@ -73,11 +73,13 @@ def test_counted_programs_keep_the_public_results():
     srcs = jnp.asarray(firsts, jnp.int32)
     for counted, public in ((K.bfs_multi_steps, K.bfs_multi),
                             (K.sssp_multi_steps, K.sssp_multi)):
-        rows, trips = counted(ga, srcs)
+        rows, trips, passes = counted(ga, srcs)
         np.testing.assert_array_equal(np.asarray(rows),
                                       np.asarray(public(ga, srcs)))
         np.testing.assert_array_equal(np.asarray(trips), [7, 4, 2])
         assert trips.dtype == jnp.int32
+        # in-degree 2 inside every path: one pass of the segmented scan
+        assert int(passes) == 1 and passes.dtype == jnp.int32
 
 
 def test_session_launch_span_carries_steps_and_lanes():
@@ -95,3 +97,30 @@ def test_session_launch_span_carries_steps_and_lanes():
     # lanes of 6, 3 and 5 steps (the third root sits one vertex in)
     assert snap["engine_lane_steps_total"] == {"kernel=bfs": 6 + 3 + 5}
     assert snap["engine_lane_slots_total"] == {"kernel=bfs": 6 * 4}
+
+
+def _star(k):
+    """A hub (vertex 0) linked both ways to 2**k leaves: in-degree 2**k."""
+    leaves = list(range(1, 2**k + 1))
+    return from_edges(2**k + 1, leaves + [0] * 2**k, [0] * 2**k + leaves,
+                      name="star")
+
+
+def _directed_path(n):
+    return from_edges(n, range(n - 1), range(1, n), name="dipath")
+
+
+@pytest.mark.parametrize("kernel", ["bfs", "sssp"])
+@pytest.mark.parametrize("graph,passes,steps", [
+    (_directed_path(9), 0, 9),    # in-degree <= 1: the runs need no pass
+    (_star(3), 3, 2),             # in-degree 2**3: three passes a step
+    (_star(4), 4, 2),
+])
+def test_segment_passes_are_passes_times_steps(kernel, graph, passes,
+                                               steps):
+    backend = SingleDeviceBackend()
+    backend.run(backend.prepare(graph), kernel, [0])
+    snap = backend.metrics.snapshot()["counters"]
+    assert snap["engine_kernel_steps_total"] == {f"kernel={kernel}": steps}
+    assert snap["engine_segment_passes_total"] == {
+        f"kernel={kernel}": passes * steps}

@@ -10,8 +10,7 @@ not fit. Graph shapes are the smoke's Graph500 Kronecker deployment, at
 the largest scale whose compile takes a few seconds: scale 22
 (V = 2**22, E = 16 V) for the multi-source kernels, whose batches the
 device bound cuts there, and 21 for PR and CC. The smoke itself serves
-scale 20, where the v5e compiler spends ~30 s on `bfs_multi` and
-`bc_multi`.
+scale 20, where the v5e compiler spends ~30 s on `bc_multi`.
 
 The topology is described inside a module fixture and never at import
 time: only one process at a time may load the TPU library, and the
@@ -20,6 +19,7 @@ tests run under several workers.
 from __future__ import annotations
 
 import os
+import re
 
 import pytest
 
@@ -65,7 +65,7 @@ def _graph(sharding, num_vertices: int, num_edges: int,
     return GraphArrays(arr(v + 1), arr(e), arr(e), arr(v + 1), arr(e),
                        arr(e), arr(v), arr(v), arr(e),
                        arr(v, jnp.bool_) if masks else None,
-                       arr(e, jnp.bool_) if masks else None)
+                       arr(e, jnp.bool_) if masks else None, arr(e))
 
 
 def _kron(scale: int) -> tuple[int, int]:
@@ -95,12 +95,29 @@ def _multi_source(fn, kernel: str, sharding, scale: int, batch: int,
     hlo = compiled.as_text()
     for scope in scopes:
         assert f"/{scope}/" in hlo, scope
+    if scopes:
+        # the level / round loop reduces over the dst-sorted in-CSR: no
+        # sort and no scatter runs inside it (the initial `.at[source]`
+        # scatter is outside the loop)
+        assert not _loop_sorts_and_scatters(hlo)
     # the byte model the scheduler bounds batches with must cover what
     # the compiler actually allocates
     model = launch_bytes(kernel, batch, v, e)
     assert m.temp_size_in_bytes <= model, (
         f"{kernel} S={batch}: compiler temp {m.temp_size_in_bytes} B > "
         f"modelled {model} B")
+
+
+def _loop_sorts_and_scatters(hlo: str) -> list[str]:
+    """The sort and scatter ops of a compiled program whose op_name puts
+    them inside a while loop's body."""
+    found = []
+    for line in hlo.splitlines():
+        op = re.search(r"= \S+ (sort|scatter)\(", line)
+        name = re.search(r'op_name="([^"]*)"', line)
+        if op and name and "/while/body/" in name.group(1):
+            found.append(f"{op.group(1)}: {name.group(1)}")
+    return found
 
 
 def _smoke_batch(kernel: str, scale: int) -> int:
@@ -115,13 +132,13 @@ def test_compile_bfs_multi(one_chip):
     # the program the engine serves: the rows plus each lane's trip count
     _multi_source(K.bfs_multi_steps, "bfs", one_chip, SMOKE_SCALE,
                   _smoke_batch("bfs", SMOKE_SCALE),
-                  ("bfs_frontier_gather", "bfs_segment_scatter"))
+                  ("bfs_frontier_gather", "bfs_segment_reduce"))
 
 
 def test_compile_sssp_multi(one_chip):
     _multi_source(K.sssp_multi_steps, "sssp", one_chip, SMOKE_SCALE,
                   _smoke_batch("sssp", SMOKE_SCALE),
-                  ("sssp_candidate_gather", "sssp_segment_scatter"))
+                  ("sssp_candidate_gather", "sssp_segment_reduce"))
 
 
 def test_compile_bc_multi_at_batch_bound(one_chip):
