@@ -1,6 +1,8 @@
 """Requests answered in the window over the window's seconds (host clock).
-The window is whole bursts: it ends when the burst in flight at
-``--seconds`` completes."""
+Closed loop: the window is whole bursts, from the first enqueue until
+the burst in flight at ``--seconds`` completes. Open loop: the window
+runs from the first arrival's due time to the moment the last answer of
+the arrivals due in ``[0, --seconds)`` is ready."""
 
 
 def read(ctx):

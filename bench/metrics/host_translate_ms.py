@@ -1,7 +1,9 @@
 """Host seconds per launch around the device wait: the session's
 ``translate`` span plus its ``launch`` span's time outside the
-``device_sync`` child (dispatch, the device-to-host copy, slices and the
-permutation gather back to original ids)."""
+``device_sync`` child (dispatch, the device-to-host copy and the slices
+of the rows to their real shape). The permutation gather back to
+original ids is not in it: it runs after ``launch`` closes, as the
+``unpermute`` span."""
 
 
 def read(ctx):

@@ -1,5 +1,8 @@
-"""Median of enqueue -> ``QueryFuture.result()`` returned, over every
-request of the window (host clock)."""
+"""Median request latency over every request of the window (host
+clock). Closed loop: enqueue -> ``QueryFuture.result()`` returned, read
+in enqueue order. Open loop: the request's due time -> the moment its
+answer is ready, so a stall counts against every request due during it
+and a fast answer is not timed behind a slow one."""
 import numpy as np
 
 

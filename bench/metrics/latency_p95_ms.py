@@ -1,5 +1,7 @@
-"""95th percentile of enqueue -> ``QueryFuture.result()`` returned, over
-every request of the window (host clock; not a median of chunks)."""
+"""95th percentile of request latency over every request of the window
+(host clock; not a median of chunks). Closed loop: enqueue ->
+``QueryFuture.result()`` returned, read in enqueue order. Open loop:
+the request's due time -> the moment its answer is ready."""
 import numpy as np
 
 

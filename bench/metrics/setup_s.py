@@ -1,6 +1,9 @@
-"""Process start -> window start (host clock): graph generation,
-``from_edges`` + ``register``, the warm-up burst of the cell's own
-shape (with its compiles, or their load from the cache)."""
+"""Process start -> window start (host clock): graph generation, the
+choice of roots, ``from_edges`` + ``register``, and the warm-up (with
+its compiles, or their load from the cache): a closed loop's burst of
+the cell's own shape, or, for each class of an open loop, sets of one,
+two, ... requests served together, every launch size the scheduler can
+coalesce its arrivals into."""
 
 
 def read(ctx):

@@ -24,13 +24,15 @@ def test_sssp_least_bytes_by_hand():
 def test_roofline_share_from_a_trace():
     from types import SimpleNamespace
     roofline = harness.load_module(ROOT / "bench/metrics/bfs_roofline.py")
+    bfs = SimpleNamespace(program="bfs_multi", launches=2, sources=3,
+                          bytes_model=ROOT / "bench/bytes/bfs.py")
     ctx = SimpleNamespace(
-        traffic={"kernel": "bfs", "program": "bfs_multi"},
+        classes={"bfs": bfs},
         trace={"programs": {"jit_bfs_multi": {"seconds": 2e-6, "count": 2}}},
-        peaks={"hbm_bytes_per_s": 100e6}, num_vertices=4, num_edges=5,
-        counters={"engine_launches_total": 2, "engine_sources_total": 3},
-        bytes_model=ROOT / "bench/bytes/bfs.py")
+        peaks={"hbm_bytes_per_s": 100e6}, num_vertices=4, num_edges=5)
     # 132 bytes at 100 MB/s take 1.32 us of the 2 us the program ran
     assert abs(roofline.read(ctx) - 66.0) < 1e-9
-    ctx.traffic = {"kernel": "sssp", "program": "sssp_multi"}
+    ctx.classes = {"sssp": SimpleNamespace(
+        program="sssp_multi", launches=2, sources=3,
+        bytes_model=ROOT / "bench/bytes/sssp.py")}
     assert roofline.read(ctx) is None

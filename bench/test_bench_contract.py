@@ -12,7 +12,8 @@ import sys
 import pytest
 
 from bench import harness
-from bench.conftest import ROOT, copy_benchmark
+from bench.conftest import (ROOT, TINY, TINY_MIX, TINY_TRAFFIC, add_mix,
+                            copy_benchmark)
 
 BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -99,8 +100,10 @@ def test_configs_state_their_cuts():
 @pytest.mark.parametrize("cell", CELLS)
 def test_every_file_of_a_cell_is_found_by_name(cell):
     spec = harness.resolve(ROOT, cell)
-    for path in (spec.generator, spec.reference, spec.bytes_model):
-        assert path.is_file(), path
+    assert spec.generator.is_file(), spec.generator
+    for cls in spec.classes:
+        for path in (cls.reference, cls.bytes_model):
+            assert path.is_file(), path
     names = {m["name"] for m in spec.end_to_end + spec.per_layer}
     for name in names:
         assert (spec.bench_dir / "metrics" / f"{name}.py").is_file()
@@ -166,6 +169,110 @@ def test_a_traffic_key_the_harness_does_not_drive_is_refused(tmp_path):
     path.write_text(json.dumps({**mix, "loop": "open", "clients": 4}))
     with pytest.raises(harness.CellError, match="clients"):
         harness.resolve(root, "grid100.bfs.burst4")
+
+
+def test_a_mix_file_resolves(tmp_path):
+    root = copy_benchmark(tmp_path)
+    spec = harness.resolve(root, add_mix(root))
+    assert [(c.kernel, c.program, c.sources, c.share, c.root_depth)
+            for c in spec.classes] == [("bfs", "bfs_multi", 1, 0.75, 3),
+                                       ("sssp", "sssp_multi", 4, 0.25, None)]
+    for c in spec.classes:
+        assert c.reference.is_file() and c.bytes_model.is_file()
+    # a mix is an open loop: it has arrivals and no burst
+    assert spec.arrivals == TINY_MIX["arrivals"] and spec.burst is None
+
+
+def _mix(**changes) -> dict:
+    mix = json.loads(json.dumps(TINY_MIX))
+    for where, value in changes.items():
+        if where == "top":
+            mix.update(value)
+        elif where == "arrivals":
+            mix["arrivals"].update(value)
+        else:
+            mix["classes"][int(where[-1])].update(value)
+    return mix
+
+
+@pytest.mark.parametrize("mix,match", [
+    (_mix(top={"clients": 4}), "clients"),
+    (_mix(class1={"priority": 2}), "priority"),
+    (_mix(arrivals={"jitter_s": 0.1}), "jitter_s"),
+    (_mix(class0={"burst": 4}), "burst"),
+])
+def test_an_unknown_key_of_a_mix_or_its_classes_is_refused(tmp_path, mix,
+                                                           match):
+    root = copy_benchmark(tmp_path)
+    with pytest.raises(harness.CellError, match=match):
+        harness.resolve(root, add_mix(root, mix))
+
+
+def _one_kernel_with_arrivals() -> dict:
+    mix = json.loads((ROOT / "bench/traffic/bfs.burst4.json").read_text())
+    return {**mix, "arrivals": dict(TINY_MIX["arrivals"])}
+
+
+@pytest.mark.parametrize("mix,match", [
+    ({k: v for k, v in TINY_MIX.items() if k != "arrivals"}, "arrivals"),
+    (_mix(class1={"share": 0.5}), "shares"),
+    (_mix(class1={"kernel": "bfs", "program": "bfs_multi"}), "one class"),
+    (_mix(arrivals={"process": "uniform"}), "poisson"),
+    (_mix(arrivals={"schedule_seed": None}), "schedule_seed"),
+    # an open loop of one kernel is a mix of one class
+    (_one_kernel_with_arrivals(), "arrivals"),
+])
+def test_a_mix_the_harness_cannot_drive_is_refused(tmp_path, mix, match):
+    root = copy_benchmark(tmp_path)
+    with pytest.raises(harness.CellError, match=match):
+        harness.resolve(root, add_mix(root, mix))
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_closed_loop_is_one_class_with_its_burst(cell):
+    spec = harness.resolve(ROOT, cell)
+    (cls,) = spec.classes
+    assert (cls.kernel, cls.program, cls.sources, cls.share) == (
+        spec.traffic["kernel"], spec.traffic["program"], 1, 1.0)
+    assert spec.burst == spec.traffic["burst"] and spec.arrivals is None
+
+
+# (warm-up roots, window roots) of each existing traffic file at the
+# tiny sizes of the tests, as the harness drew them before it drove
+# mixes: their count and a digest of their int64 bytes
+ROOTS_BEFORE = {
+    ("kron20.bfs.burst32", 3000000007): (32, "771d4bbe0830427a",
+                                         64, "409d4b77eb9f0e45"),
+    ("kron20.bfs.burst32", 2**33 + 5): (32, "2ed70ecb8a5c540f",
+                                        64, "f38b75f32b406a9f"),
+    ("grid100.bfs.burst4", 3000000007): (4, "8b5c0da00bbc9cbf",
+                                         339, "28dc208f21cb9a33"),
+    ("grid100.bfs.burst4", 2**33 + 5): (4, "5d80cf9e9980430b",
+                                        339, "546c1eb07f52f578"),
+    ("kron20.sssp.burst4", 3000000007): (4, "bf6f4468177cf922",
+                                         8, "1016dc4183d2df8b"),
+    ("kron20.sssp.burst4", 2**33 + 5): (4, "ef07601751ae7799",
+                                        8, "211a5b3368ddc1df"),
+}
+
+
+@pytest.mark.parametrize("cell,seed", sorted(ROOTS_BEFORE))
+def test_each_traffic_file_draws_the_roots_it_drew_before(tmp_path, cell,
+                                                          seed):
+    import hashlib
+
+    import numpy as np
+    root = copy_benchmark(tmp_path, TINY, traffic=TINY_TRAFFIC)
+    spec = harness.resolve(root, cell)
+    n, src, dst = harness.load_module(spec.generator).generate(
+        spec.config, seed)
+    warm, window = harness.split_roots(
+        spec, n, src, dst, harness.draw_roots(n, src, dst, seed))
+
+    def digest(a):
+        return hashlib.sha256(np.asarray(a, np.int64).tobytes()).hexdigest()
+    assert (len(warm), digest(warm)[:16], len(window),
+            digest(window)[:16]) == ROOTS_BEFORE[cell, seed]
 
 
 def test_unknown_device_kind_is_an_error():

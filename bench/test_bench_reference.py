@@ -32,13 +32,14 @@ def _served(spec, seed: int, count: int = 6):
 @pytest.mark.parametrize("cell", sorted(CELLS))
 def test_comparison_accepts_served_and_rejects_one_corrupt_entry(cell):
     spec = harness.resolve(ROOT, cell)
+    (cls,) = spec.classes
     n, src, dst, sample = _served(spec, seed=2**33 + 17)
-    assert harness.compare(spec, n, src, dst, sample)[
+    assert harness.compare(cls, n, src, dst, sample)[
         "mismatched_entries"] == 0
     root, row = sample[-1]
     bad = np.array(row, copy=True)
     bad[(root + 1) % n] += 1
-    assert harness.compare(spec, n, src, dst, sample[:-1] + [(root, bad)])[
+    assert harness.compare(cls, n, src, dst, sample[:-1] + [(root, bad)])[
         "mismatched_entries"] == 1
 
 
@@ -47,9 +48,9 @@ def test_control_fails_the_comparison(cell):
     spec = harness.resolve(ROOT, cell)
     n, src, dst, sample = _served(spec, seed=5)
     roots = [r for r, _ in sample]
-    reference = harness.load_module(spec.reference)
-    control = reference.control(n, src, dst, roots)
-    assert harness.compare(spec, n, src, dst, list(zip(roots, control)))[
+    (cls,) = spec.classes
+    control = harness.load_module(cls.reference).control(n, src, dst, roots)
+    assert harness.compare(cls, n, src, dst, list(zip(roots, control)))[
         "mismatched_entries"] > 0
 
 
@@ -91,7 +92,7 @@ def test_depth_of_agrees_with_the_deepest_answer(cell):
     cfg = {**spec.config, **TINY[spec.config["name"]]}
     n, src, dst = harness.load_module(spec.generator).generate(cfg, 11)
     roots = harness.draw_roots(n, src, dst, 11)[:70]
-    reference = harness.load_module(spec.reference)
+    reference = harness.load_module(spec.classes[0].reference)
     got = reference.depth_of(n, src, dst)(roots)
     if spec.traffic["kernel"] == "bfs":
         # more roots than one pass holds, each row's deepest level
@@ -114,7 +115,8 @@ def test_a_mix_with_a_root_depth_gives_every_request_that_depth(tiny_root,
         spec.config, seed)
     roots = harness.draw_roots(n, src, dst, seed)
     warm, window = harness.split_roots(spec, n, src, dst, roots)
-    depth = harness.load_module(spec.reference).depth_of(n, src, dst)
+    depth = harness.load_module(spec.classes[0].reference).depth_of(
+        n, src, dst)
     assert len(window) == spec.traffic["window_roots"]
     assert set(depth(window).tolist()) == {spec.traffic["root_depth"]}
     assert len(warm) == spec.traffic["burst"]
@@ -143,7 +145,7 @@ def test_a_mix_without_a_root_depth_warms_up_on_its_last_burst():
 
 def test_a_graph_short_of_roots_of_the_depth_is_refused(tiny_root):
     spec = harness.resolve(tiny_root, "kron20.sssp.burst4")
-    spec.traffic["root_depth"] = 10**6
+    spec.classes[0].root_depth = 10**6
     n, src, dst = harness.load_module(spec.generator).generate(spec.config, 7)
     with pytest.raises(harness.CellError, match="depth"):
         harness.split_roots(spec, n, src, dst,

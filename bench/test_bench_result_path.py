@@ -31,7 +31,7 @@ def _window(**over) -> SimpleNamespace:
                   _span("slice_out", out)]
     ctx = SimpleNamespace(
         spans=spans, bench_dir=ROOT / "bench",
-        traffic={"kernel": "bfs", "program": "bfs_multi"},
+        classes={"bfs": SimpleNamespace(program="bfs_multi")},
         trace={"programs": {"jit_bfs_multi_steps": {"seconds": 16.8,
                                                     "count": 2}}},
         counters={"engine_launches_total": 2,
