@@ -27,8 +27,11 @@ class. A mix gives ``classes``, each with those keys less ``burst`` plus
 ``sources`` per request (default 1) and ``share``, the fraction of
 arrivals in the class, and ``arrivals`` (``process`` "poisson",
 ``rate_per_s``, an optional burst overlay ``burst_every_s`` /
-``burst_size``, and ``schedule_seed``): an open loop. Any key the
-harness does not drive is refused.
+``burst_size``, and ``schedule_seed``): an open loop. A mix cell's
+``rate_per_s`` is 4/5 of the knee that ``bench/knee.py`` finds for it
+on the chip (the highest swept rate whose p95 latency is at most twice
+that at the lowest, every arrival answered), rounded down to 0.05 req/s.
+Any key the harness does not drive is refused.
 
 A run: set-up (generate the graph from the seed, choose the roots,
 ``from_edges`` and ``EngineSession.register``, warm up, result cache
@@ -578,6 +581,30 @@ def _counters(session) -> dict:
     return out
 
 
+def _kernel_counters(session) -> dict:
+    """By kernel: every counter summed over its label sets whose
+    ``kernel`` label names that kernel (the backend's step, lane and
+    pass counters), so that a mix's classes are read apart."""
+    out: dict = collections.defaultdict(dict)
+    for name, value in session.metrics().snapshot()["counters"].items():
+        if not isinstance(value, dict):
+            continue
+        for key, count in value.items():
+            labels = dict(part.split("=", 1) for part in key.split(",")
+                          if "=" in part)
+            if "kernel" in labels:
+                mine = out[labels["kernel"]]
+                mine[name] = mine.get(name, 0) + count
+    return dict(out)
+
+
+def _kernel_deltas(before: dict, after: dict) -> dict:
+    """The window's delta of each kernel's counters."""
+    return {kernel: {name: value - before.get(kernel, {}).get(name, 0)
+                     for name, value in counters.items()}
+            for kernel, counters in after.items()}
+
+
 def _find_xplane(directory: str) -> pathlib.Path:
     found = sorted(pathlib.Path(directory).rglob("*.xplane.pb"))
     if not found:
@@ -623,11 +650,13 @@ def compare(cls, num_vertices: int, src, dst, sample: list) -> dict:
     return {"mismatched_entries": int(bad), "entries": int(entries)}
 
 
-def _class_context(classes: list, spans: list, latencies: dict) -> dict:
+def _class_context(classes: list, spans: list, latencies: dict,
+                   kernel_counters: dict) -> dict:
     """Per class, by kernel: its program, bytes model, the requests it
-    answered and their latencies, the real sources they carried, and the
+    answered and their latencies, the real sources they carried, the
     launches the window's ``launch`` spans attribute to its kernel, with
-    each span's seconds (``launch_s``)."""
+    each span's seconds (``launch_s``), and the window's delta of the
+    counters labelled with its kernel (``counters``)."""
     launch_s = collections.defaultdict(list)
     for s in spans:
         if s["name"] == "launch":
@@ -639,7 +668,8 @@ def _class_context(classes: list, spans: list, latencies: dict) -> dict:
             kernel=c.kernel, program=c.program, bytes_model=c.bytes_model,
             answered=len(mine), latencies_s=mine,
             launches=len(launch_s[c.kernel]), launch_s=launch_s[c.kernel],
-            sources=len(mine) * c.sources)
+            sources=len(mine) * c.sources,
+            counters=kernel_counters.get(c.kernel, {}))
     return out
 
 
@@ -755,6 +785,7 @@ def run_cell(spec, seed: int, seconds: float, trace: bool,
             compiles[0] += 1
 
     events0, counters0 = len(session.tracer.events), _counters(session)
+    kernels0 = _kernel_counters(session)
     jax.monitoring.register_event_duration_secs_listener(on_event)
     annotate = (jax.profiler.TraceAnnotation("bench.window") if trace
                 else contextlib.nullcontext())
@@ -814,6 +845,7 @@ def run_cell(spec, seed: int, seconds: float, trace: bool,
     spans = session.tracer.events[events0:]
     counters1 = _counters(session)
     counters = {k: v - counters0.get(k, 0) for k, v in counters1.items()}
+    kernel_counters = _kernel_deltas(kernels0, _kernel_counters(session))
     reduced = None
     if trace:
         session.stop_profiler()
@@ -843,9 +875,10 @@ def run_cell(spec, seed: int, seconds: float, trace: bool,
         bench_dir=spec.bench_dir, setup_s=setup_s, register_s=register_s,
         window_s=window_s, latencies_s=latencies, answered=len(latencies),
         attempted=attempted, spans=spans, counters=counters,
+        kernel_counters=kernel_counters,
         xla_compiles=compiles[0], trace=reduced, peaks=peaks,
         num_vertices=num_vertices, num_edges=len(src),
-        classes=_class_context(classes, spans, by_class),
+        classes=_class_context(classes, spans, by_class, kernel_counters),
         arrival_lag_s=lags)
     if open_loop:
         for c in ctx.classes.values():
@@ -906,6 +939,30 @@ def run_cell(spec, seed: int, seconds: float, trace: bool,
     return result
 
 
+def chip_peaks(spec) -> dict:
+    """The peaks of the chip JAX finds, from ``bench/peaks.json``; a
+    `CellError` where it finds no TPU, fewer chips than the cell asks
+    for, or a kind the table lacks."""
+    import jax
+    devices = jax.devices()
+    if devices[0].platform != "tpu" or len(devices) < spec.cell["chips"]:
+        raise CellError(f"{spec.cell['name']!r} needs {spec.cell['chips']} "
+                        f"TPU chip(s); JAX reports {len(devices)} "
+                        f"{devices[0].platform!r} device(s)")
+    return peaks_for(devices[0].device_kind, spec.bench_dir)
+
+
+def use_compile_cache() -> None:
+    """JAX's persistent compile cache, at a fixed path inside the
+    checkout whatever the environment names, so that two checkouts never
+    share one and only a cell's first run in a checkout compiles."""
+    import jax
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
+    from repro.compile_cache import enable_compile_cache
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    log(f"compile cache: {enable_compile_cache()}")
+
+
 def main(argv=None, t_start: float | None = None) -> int:
     t_start = time.perf_counter() if t_start is None else t_start
     ap = argparse.ArgumentParser(description="run one benchmark cell once")
@@ -920,23 +977,12 @@ def main(argv=None, t_start: float | None = None) -> int:
     except (CellError, ImportError, KeyError, OSError) as exc:
         log(f"bench: cannot run {args.workload!r}: {exc}")
         return 2
-    import jax
-    devices = jax.devices()
-    if devices[0].platform != "tpu" or len(devices) < spec.cell["chips"]:
-        log(f"bench: {args.workload!r} needs {spec.cell['chips']} TPU chip(s); "
-            f"JAX reports {len(devices)} {devices[0].platform!r} device(s)")
-        return 2
     try:
-        peaks = peaks_for(devices[0].device_kind, spec.bench_dir)
+        peaks = chip_peaks(spec)
     except CellError as exc:
         log(f"bench: {exc}")
         return 2
-    # the cache lives at a fixed path inside the checkout, whatever the
-    # environment names, so that two checkouts never share one
-    os.environ["JAX_COMPILATION_CACHE_DIR"] = str(ROOT / ".jax_cache")
-    from repro.compile_cache import enable_compile_cache
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-    log(f"compile cache: {enable_compile_cache()}")
+    use_compile_cache()
     result = run_cell(spec, args.seed, args.seconds, bool(args.trace),
                       t_start, peaks)
     print(json.dumps(result), flush=True)

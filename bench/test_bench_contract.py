@@ -19,6 +19,16 @@ BENCH = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 CELLS = [w["name"] for w in BENCH["workloads"]]
+CLOSED_CELLS = [w["name"] for w in BENCH["workloads"] if "arrivals" not in
+                json.loads((ROOT / "bench/traffic" /
+                            f"{w['traffic']}.json").read_text())]
+# the repo's open-loop cells; where it has none yet, the test mix added
+# to a copy of the benchmark (None) stands in, so that the open loop's
+# check always has a case
+OPEN_CELLS = [c for c in CELLS if c not in CLOSED_CELLS] or [None]
+# what a class's reference gives the harness: the answers, the size of a
+# request (``depth_of``, ``DEPTH_BATCH`` roots at a time) and the control
+REFERENCE_NAMES = ("solve", "depth_of", "DEPTH_BATCH", "control")
 KEYS = {"config": {"name", "source", "file", "reduced", "why"},
         "workload": {"name", "config", "traffic", "chips", "why"},
         "end_to_end": {"name", "unit", "better", "bound", "source"},
@@ -97,9 +107,10 @@ def test_configs_state_their_cuts():
             assert key in cfg and not key.endswith(("_dim", "_rank"))
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_file_of_a_cell_is_found_by_name(cell):
-    spec = harness.resolve(ROOT, cell)
+def check_files_found(root: pathlib.Path, cell: str) -> None:
+    """Every file the cell names is found by name under ``root``, and
+    the cell reports every end-to-end metric and some per-layer one."""
+    spec = harness.resolve(root, cell)
     assert spec.generator.is_file(), spec.generator
     for cls in spec.classes:
         for path in (cls.reference, cls.bytes_model):
@@ -108,8 +119,52 @@ def test_every_file_of_a_cell_is_found_by_name(cell):
     for name in names:
         assert (spec.bench_dir / "metrics" / f"{name}.py").is_file()
     assert {m["name"] for m in spec.end_to_end} == {
-        m["name"] for m in BENCH["end_to_end"]}
+        m["name"] for m in harness.read_json(root / "BENCHMARK.json")[
+            "end_to_end"]}
     assert spec.per_layer
+
+
+def check_traffic(root: pathlib.Path, cell: str) -> None:
+    """The cell's traffic as the harness drives it. A closed loop (no
+    ``arrivals``) is one class, the traffic file's kernel and program,
+    one source a request, share 1, with the file's burst. An open loop
+    has its arrivals and no burst, one class per kernel whose shares add
+    up to 1, and each class a reference that gives the harness what it
+    calls, a bytes model and at least one checked answer."""
+    spec = harness.resolve(root, cell)
+    if "arrivals" not in spec.traffic:
+        (cls,) = spec.classes
+        assert (cls.kernel, cls.program, cls.sources, cls.share) == (
+            spec.traffic["kernel"], spec.traffic["program"], 1, 1.0)
+        assert spec.burst == spec.traffic["burst"] and spec.arrivals is None
+        return
+    assert spec.arrivals == spec.traffic["arrivals"] and spec.burst is None
+    kernels = [c.kernel for c in spec.classes]
+    assert kernels and len(set(kernels)) == len(kernels), kernels
+    assert sum(c.share for c in spec.classes) == pytest.approx(1.0)
+    for cls in spec.classes:
+        assert cls.bytes_model.is_file(), cls.bytes_model
+        assert cls.check_sample >= 1, cls.kernel
+        reference = harness.load_module(cls.reference)
+        missing = [n for n in REFERENCE_NAMES if not hasattr(reference, n)]
+        assert not missing, (cls.reference, missing)
+
+
+# every check of one cell, each a function of (benchmark root, cell)
+CELL_CHECKS = (check_files_found, check_traffic)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_file_of_a_cell_is_found_by_name(cell):
+    check_files_found(ROOT, cell)
+
+
+def test_a_mix_cell_added_with_files_alone_passes_every_cell_check(
+        tmp_path):
+    root = copy_benchmark(tmp_path)
+    cell = add_mix(root, name="kron20.mix.open")
+    for check in CELL_CHECKS:
+        check(root, cell)
 
 
 def test_a_new_traffic_file_and_cell_need_no_edit(tmp_path):
@@ -228,13 +283,39 @@ def test_a_mix_the_harness_cannot_drive_is_refused(tmp_path, mix, match):
         harness.resolve(root, add_mix(root, mix))
 
 
-@pytest.mark.parametrize("cell", CELLS)
+@pytest.mark.parametrize("cell", CLOSED_CELLS)
 def test_a_closed_loop_is_one_class_with_its_burst(cell):
-    spec = harness.resolve(ROOT, cell)
-    (cls,) = spec.classes
-    assert (cls.kernel, cls.program, cls.sources, cls.share) == (
-        spec.traffic["kernel"], spec.traffic["program"], 1, 1.0)
-    assert spec.burst == spec.traffic["burst"] and spec.arrivals is None
+    check_traffic(ROOT, cell)
+
+
+@pytest.mark.parametrize("cell", OPEN_CELLS)
+def test_an_open_loop_is_one_class_per_kernel_with_its_arrivals(tmp_path,
+                                                                cell):
+    root = ROOT
+    if cell is None:
+        root = copy_benchmark(tmp_path)
+        cell = add_mix(root)
+    check_traffic(root, cell)
+
+
+@pytest.mark.parametrize("fault,match", [("no_reference", "bc.py"),
+                                         ("no_control", "control")])
+def test_an_open_loop_the_benchmark_cannot_check_fails_its_cell_check(
+        tmp_path, fault, match):
+    root = copy_benchmark(tmp_path)
+    mix = json.loads(json.dumps(TINY_MIX))
+    if fault == "no_reference":
+        # a class whose kernel has no reference to check its answers by
+        mix["classes"][1].update(kernel="bc", program="bc_multi")
+    else:
+        # a reference without the control that bench/control.py runs
+        path = root / "bench/reference/sssp.py"
+        path.write_text(path.read_text().replace("def control(",
+                                                 "def _control("))
+    cell = add_mix(root, mix)
+    with pytest.raises(AssertionError, match=match):
+        for check in CELL_CHECKS:
+            check(root, cell)
 
 
 # (warm-up roots, window roots) of each existing traffic file at the

@@ -306,12 +306,15 @@ def test_launches_are_attributed_to_a_class_by_kernel(tiny_root):
                             ("bfs", 3e6))]
     spans += [{"name": "translate", "dur": 1e3, "args": {"kernel": "bfs"}}]
     classes = harness._class_context(
-        spec.classes, spans, {"bfs": [0.5, 0.25, 1.0], "sssp": [2.0]})
+        spec.classes, spans, {"bfs": [0.5, 0.25, 1.0], "sssp": [2.0]},
+        {"bfs": {"engine_kernel_steps_total": 21}})
     assert (classes["bfs"].launches, classes["bfs"].sources,
             classes["bfs"].answered) == (3, 3, 3)
     assert classes["bfs"].launch_s == pytest.approx([4.0, 5.0, 3.0])
     assert (classes["sssp"].launches, classes["sssp"].sources,
             classes["sssp"].program) == (1, 4, "sssp_multi")
+    assert (classes["bfs"].counters, classes["sssp"].counters) == (
+        {"engine_kernel_steps_total": 21}, {})
     # each kernel's roofline reads its own class of the mix
     ctx = types.SimpleNamespace(
         traffic=spec.traffic, classes=classes, num_vertices=4, num_edges=5,
@@ -393,3 +396,55 @@ def test_a_broken_open_loop_is_not_correct(tiny_root, monkeypatch, fault):
     result = _run(tiny_root, cell)
     assert result["correct"] is False
     assert result["compared"]["mismatched_entries"]["value"] > 0
+
+
+class _Snapshot:
+    """A session whose metrics snapshot holds ``counters``."""
+
+    def __init__(self, counters: dict):
+        self.counters = counters
+
+    def metrics(self):
+        return self
+
+    def snapshot(self):
+        return {"counters": self.counters, "gauges": {}, "histograms": {}}
+
+
+def test_kernel_labelled_counters_are_read_per_kernel():
+    session = _Snapshot({
+        "engine_kernel_steps_total": {"kernel=bfs": 14, "kernel=sssp": 13},
+        "engine_lane_slots_total": {"kernel=bfs": 448, "kernel=sssp": 52},
+        "engine_sources_total": {"backend=single": 36},
+        "engine_launches_total": 3,
+        "engine_other_total": {"backend=single,kernel=bfs": 2,
+                               "backend=sharded,kernel=bfs": 5}})
+    assert harness._kernel_counters(session) == {
+        "bfs": {"engine_kernel_steps_total": 14,
+                "engine_lane_slots_total": 448, "engine_other_total": 7},
+        "sssp": {"engine_kernel_steps_total": 13,
+                 "engine_lane_slots_total": 52}}
+    # the whole-window counters still sum every label set
+    assert harness._counters(session) == {
+        "engine_kernel_steps_total": 27, "engine_lane_slots_total": 500,
+        "engine_sources_total": 36, "engine_launches_total": 3,
+        "engine_other_total": 7}
+    before = {"bfs": {"engine_kernel_steps_total": 4}}
+    assert harness._kernel_deltas(before, harness._kernel_counters(session))[
+        "bfs"]["engine_kernel_steps_total"] == 10
+
+
+def test_a_mix_reads_each_class_its_own_step_counters(tiny_root,
+                                                      monkeypatch):
+    seen = []
+    monkeypatch.setattr(harness, "read_metrics",
+                        lambda entries, ctx, bench_dir: seen.append(ctx)
+                        or {})
+    _run(tiny_root, add_mix(tiny_root))
+    (ctx,) = seen
+    steps = {k: c.counters.get("engine_kernel_steps_total", 0)
+             for k, c in ctx.classes.items()}
+    assert set(steps) == {"bfs", "sssp"} and all(steps.values())
+    assert sum(steps.values()) == ctx.counters["engine_kernel_steps_total"]
+    for kernel, cls in ctx.classes.items():
+        assert cls.counters == ctx.kernel_counters[kernel]
