@@ -18,8 +18,10 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from ..core.csr import Graph
 
@@ -43,6 +45,9 @@ class GraphArrays(NamedTuple):
     # relaxation. None (a positional build from the CSR fields alone)
     # makes the SSSP program derive them from ``weights`` itself.
     t_weights: jnp.ndarray | None = None
+    # (E,) int32 each out-arc's position in the in-CSR, for the BFS push
+    # step. None (a positional build) makes the program derive it.
+    out_to_in: jnp.ndarray | None = None
 
     @property
     def num_vertices(self) -> int:
@@ -51,6 +56,19 @@ class GraphArrays(NamedTuple):
     @property
     def num_edges(self) -> int:
         return self.indices.shape[0]
+
+
+@jax.jit
+def in_csr_positions(indices: jnp.ndarray) -> jnp.ndarray:
+    """(E,) out-CSR destinations -> (E,) int32 each out-arc's position in
+    the in-CSR. The in-CSR is the out-CSR's arcs stably sorted by
+    destination (`Graph.transpose` sorts the (dst, src) keys), so the map
+    is that sort's inverse permutation; a bucket's sentinel tail, whose
+    destination is the last vertex, maps to itself. Two sorts on the
+    device, no scatter."""
+    pos = jnp.arange(indices.shape[0], dtype=jnp.int32)
+    _, order = lax.sort((indices, pos), num_keys=1, is_stable=True)
+    return lax.sort((order, pos), num_keys=1)[1]
 
 
 def edge_weights(src: np.ndarray, dst: np.ndarray,
@@ -88,6 +106,8 @@ def to_device(g: Graph, weight_seed: int = 17,
     restricted to the real ``[:V]`` prefix equal the unpadded run. When
     edges are padded there must be at least one padded vertex to host the
     sentinels — `engine.backends.bucket_dims` guarantees that.
+    The in-CSR position of each out-arc (``out_to_in``) is derived on the
+    device from the uploaded destinations.
     """
     t = g.transpose
     src = g.edge_src.astype(np.int64)
@@ -109,9 +129,10 @@ def to_device(g: Graph, weight_seed: int = 17,
             raise ValueError("edge padding needs at least one padded vertex "
                              "to host sentinel self-loops")
     if (num_v, num_e) == (n, e):
+        indices = jnp.asarray(g.indices, jnp.int32)
         return GraphArrays(
             indptr=jnp.asarray(g.indptr, jnp.int32),
-            indices=jnp.asarray(g.indices, jnp.int32),
+            indices=indices,
             src=jnp.asarray(src, jnp.int32),
             t_indptr=jnp.asarray(t.indptr, jnp.int32),
             t_indices=jnp.asarray(t.indices, jnp.int32),
@@ -120,6 +141,7 @@ def to_device(g: Graph, weight_seed: int = 17,
             in_degree=jnp.asarray(g.in_degree, jnp.int32),
             weights=jnp.asarray(w, jnp.int32),
             t_weights=jnp.asarray(t_w, jnp.int32),
+            out_to_in=in_csr_positions(indices),
         )
 
     sentinel = num_v - 1  # always a padded vertex when sentinel edges exist
@@ -146,9 +168,10 @@ def to_device(g: Graph, weight_seed: int = 17,
     vertex_valid[:n] = True
     edge_valid = np.zeros(num_e, bool)
     edge_valid[:e] = True
+    indices = jnp.asarray(pad_e(g.indices, sentinel))
     return GraphArrays(
         indptr=jnp.asarray(pad_ptr(g.indptr)),
-        indices=jnp.asarray(pad_e(g.indices, sentinel)),
+        indices=indices,
         src=jnp.asarray(pad_e(src, sentinel)),
         t_indptr=jnp.asarray(pad_ptr(t.indptr)),
         t_indices=jnp.asarray(pad_e(t.indices, sentinel)),
@@ -159,4 +182,5 @@ def to_device(g: Graph, weight_seed: int = 17,
         vertex_valid=jnp.asarray(vertex_valid),
         edge_valid=jnp.asarray(edge_valid),
         t_weights=jnp.asarray(pad_e(t_w, 1)),
+        out_to_in=in_csr_positions(indices),
     )

@@ -1,7 +1,8 @@
 """The six GAP-style graph kernels in pure JAX (paper §5.1).
 
 Each kernel is edge-parallel (COO segment ops; BFS and SSSP pull over the
-in-CSR with a segmented scan) with `lax.while_loop` outer iteration — the
+in-CSR with a segmented scan, BFS pushing a small frontier's out-arcs
+instead) with `lax.while_loop` outer iteration — the
 JAX-native rendering of the level-synchronous / iterative structure the
 paper's C++ GAPS kernels use. All are `jit`-able; vertex property arrays
 are the reuse-heavy state the paper reorders for.
@@ -22,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .graph_arrays import GraphArrays
+from .graph_arrays import GraphArrays, in_csr_positions
 
 INF_I32 = jnp.int32(2**31 - 1)
 
@@ -117,39 +118,97 @@ def _unpack_lanes(words: jnp.ndarray, num_lanes: int) -> jnp.ndarray:
     return (per_lane & _lane_bits(num_lanes)) != 0
 
 
+def _out_to_in(g: GraphArrays) -> jnp.ndarray:
+    """Each out-arc's position in the in-CSR; an upload without the map
+    derives it."""
+    if g.out_to_in is not None:
+        return g.out_to_in
+    return in_csr_positions(g.indices)
+
+
+def _pull_words(g: GraphArrays, front: jnp.ndarray) -> jnp.ndarray:
+    """(W, V) frontier words -> (W, E) the word of each in-arc's source:
+    a gather over every in-arc, whatever the frontier."""
+    active = front[:, g.t_indices]
+    if g.edge_valid is not None:
+        active = jnp.where(g.edge_valid, active, jnp.uint32(0))
+    return active
+
+
+def _push_words(g: GraphArrays, out_to_in: jnp.ndarray, front: jnp.ndarray,
+                degree: jnp.ndarray, arcs: jnp.ndarray, slots: int
+                ) -> jnp.ndarray:
+    """`_pull_words` from the frontier's own out-arcs, when the frontier
+    vertices' ``degree`` (their out-degree, 0 off the frontier) adds up
+    to ``arcs`` <= ``slots``: the arcs are laid out over ``slots`` slots in
+    vertex order, and each writes its source's word at its in-CSR
+    position into zeros. Every gather and scatter here is indexed by
+    slots or by vertices, none by arcs. The positions are distinct, so
+    setting them is exact; the frontier holds only real vertices, so no
+    sentinel arc is written."""
+    n, e = g.num_vertices, g.num_edges
+    vid = jnp.arange(n, dtype=jnp.int32)
+    slot = jnp.arange(slots, dtype=jnp.int32)
+    first = jnp.cumsum(degree) - degree      # each vertex's first slot
+    # each frontier vertex marks its first slot with its id, and the
+    # running max carries the id over its slots (ids ascend with slots);
+    # every other vertex, and any past the slots, writes past the end
+    live = (degree > 0) & (first < slots)
+    mark = jnp.where(live, first, slots + vid)
+    owner = lax.cummax(jnp.zeros((slots,), jnp.int32).at[mark].set(
+        vid, mode="drop", unique_indices=True))
+    arc = (g.indptr[:-1] - first)[owner] + slot
+    pos = jnp.where(slot < arcs,
+                    out_to_in.at[arc].get(mode="clip"), e + slot)
+    return jnp.zeros((front.shape[0], e), front.dtype).at[:, pos].set(
+        front[:, owner], mode="drop", unique_indices=True)
+
+
 def _bfs_levels(g: GraphArrays, runs: InRuns, sources: jnp.ndarray
-                ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Level-synchronous BFS from (S,) sources at once -> (S, V) depths
-    and each lane's level-loop trip count (its eccentricity + 1)."""
+                ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Level-synchronous BFS from (S,) sources at once -> (S, V) depths,
+    each lane's level-loop trip count (its eccentricity + 1) and the
+    number of levels that pushed.
+
+    A level pushes when its frontier's out-arcs fit one sixteenth of the
+    arc axis (`_push_words`), and pulls over every in-arc otherwise; both
+    give the same per-in-arc words to the segmented OR."""
     n, s = g.num_vertices, sources.shape[0]
+    slots = g.num_edges // 16
+    out_to_in = _out_to_in(g)
     start = jnp.arange(n, dtype=jnp.int32)[None, :] == sources[:, None]
     depth0 = jnp.where(start, 0, -1).astype(jnp.int32)
     front0 = _pack_lanes(start)
 
     def cond(state):
-        _, front, _, _ = state
+        _, front, _, _, _ = state
         return (front != 0).any()
 
     def body(state):
-        depth, front, seen, level = state
-        # gather(prop, src) over the in-CSR: the hot access the paper
-        # optimizes — property reads follow the g.t_indices layout.
+        depth, front, seen, level, pushes = state
+        # each in-arc's frontier word: pushed from a small frontier's
+        # out-arcs, else gathered over the in-CSR — the hot access the
+        # paper optimizes, property reads following the t_indices layout
         with jax.named_scope("bfs_frontier_gather"):
-            active = front[:, g.t_indices]
-            if g.edge_valid is not None:
-                active = jnp.where(g.edge_valid, active, jnp.uint32(0))
+            degree = jnp.where((front != 0).any(axis=0), g.out_degree, 0)
+            arcs = degree.sum()
+            push = arcs <= slots
+            active = lax.cond(
+                push,
+                lambda f: _push_words(g, out_to_in, f, degree, arcs, slots),
+                lambda f: _pull_words(g, f), front)
         with jax.named_scope("bfs_segment_reduce"):
             touched = _segment_reduce(active, runs, jnp.bitwise_or,
                                       jnp.uint32(0))
         new = touched & ~seen
         depth = jnp.where(_unpack_lanes(new, s), level + 1, depth)
-        return depth, new, seen | new, level + 1
+        return depth, new, seen | new, level + 1, pushes + push
 
-    depth, _, _, _ = lax.while_loop(
-        cond, body, (depth0, front0, front0, jnp.int32(0)))
+    depth, _, _, _, pushes = lax.while_loop(
+        cond, body, (depth0, front0, front0, jnp.int32(0), jnp.int32(0)))
     # a lane's loop ran while its frontier was not empty: one level past
     # its deepest vertex
-    return depth, depth.max(axis=1) + 1
+    return depth, depth.max(axis=1) + 1, pushes
 
 
 @jax.jit
@@ -376,8 +435,16 @@ def sssp(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
 @jax.jit
 def bc_single_source(g: GraphArrays, source: jnp.ndarray) -> jnp.ndarray:
     """Brandes dependency accumulation for one source (unweighted)."""
+    return _brandes(g, source, bfs(g, source))
+
+
+def _brandes(g: GraphArrays, source: jnp.ndarray, depth: jnp.ndarray
+             ) -> jnp.ndarray:
+    """The forward and backward Brandes passes of one source over its BFS
+    ``depth`` row -> (V,) dependencies. Kept apart from the BFS so that
+    `bc_multi` batches only these passes: a BFS under `vmap` would batch
+    the per-level push/pull `lax.cond` into a select that runs both."""
     n = g.num_vertices
-    depth = bfs(g, source)
     max_level = depth.max()
 
     # forward: path counts sigma, level-synchronous over out-edges
@@ -542,15 +609,17 @@ def sssp_multi(g: GraphArrays, sources: jnp.ndarray) -> jnp.ndarray:
 
 @jax.jit
 def bfs_multi_steps(g: GraphArrays, sources: jnp.ndarray
-                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """`bfs_multi` plus each lane's level-loop trip count (S,) int32 and
-    the segmented reduction's passes per level () int32 — the program the
-    serving engine compiles, so that it can count the relaxation steps
-    each launch ran (a lane's count is its own, one level past its
-    deepest vertex, and the launch ran the lanes' maximum)."""
+                    ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray,
+                               jnp.ndarray]:
+    """`bfs_multi` plus each lane's level-loop trip count (S,) int32, the
+    segmented reduction's passes per level () int32 and the levels that
+    pushed () int32 — the program the serving engine compiles, so that it
+    can count the relaxation steps each launch ran (a lane's count is its
+    own, one level past its deepest vertex, and the launch ran the lanes'
+    maximum)."""
     runs = _in_runs(g)
-    rows, trips = _bfs_levels(g, runs, sources)
-    return rows, trips, runs.passes
+    rows, trips, pushes = _bfs_levels(g, runs, sources)
+    return rows, trips, runs.passes, pushes
 
 
 @jax.jit
@@ -566,8 +635,11 @@ def sssp_multi_steps(g: GraphArrays, sources: jnp.ndarray
 
 @jax.jit
 def bc_multi(g: GraphArrays, sources: jnp.ndarray) -> jnp.ndarray:
-    """Batched Brandes: (S,) sources -> (S, V) per-source dependencies."""
-    return jax.vmap(bc_single_source, in_axes=(None, 0))(g, sources)
+    """Batched Brandes: (S,) sources -> (S, V) per-source dependencies.
+    One multi-source BFS gives every lane's depths, and only the Brandes
+    passes are batched over the depth rows."""
+    depths = _bfs_levels(g, _in_runs(g), sources)[0]
+    return jax.vmap(_brandes, in_axes=(None, 0, 0))(g, sources, depths)
 
 
 @jax.jit

@@ -64,7 +64,8 @@ VECTOR_SOURCE = ("knn",)
 # jit wrapper pattern (_run_knn), mirroring the pr@spmv path.
 # An entry returns its result, or ``(rows, trips, passes)`` where it also
 # reports each lane's loop trip count and the passes of its segmented
-# reduction per step (the step counters in `run_arrays`).
+# reduction per step, BFS also the levels that pushed (the step counters
+# in `run_arrays`).
 _FNS = {
     "bfs": K.bfs_multi_steps,
     "sssp": K.sssp_multi_steps,
@@ -136,9 +137,9 @@ def estimate_device_bytes(num_vertices: int, num_edges: int,
                           batch_sources: int = 0) -> int:
     """Device footprint of serving one graph (the placement input).
 
-    CSR upload: int32 fields — 2x indptr (V+1), 6x edge-sized (indices,
-    src, t_indices, t_dst, weights, t_weights), 2x vertex-sized degrees
-    — plus 1-byte bool masks.
+    CSR upload: int32 fields — 2x indptr (V+1), 7x edge-sized (indices,
+    src, t_indices, t_dst, weights, t_weights, out_to_in), 2x
+    vertex-sized degrees — plus 1-byte bool masks.
 
     ``batch_sources`` adds the **query state** (placement v2): a
     multi-source launch of S sources holds an (S, V) int32 property
@@ -149,7 +150,7 @@ def estimate_device_bytes(num_vertices: int, num_edges: int,
     graph that fits alone but not under its real traffic's batches is
     placed sharded.
     """
-    return (4 * (2 * (num_vertices + 1) + 6 * num_edges + 2 * num_vertices)
+    return (4 * (2 * (num_vertices + 1) + 7 * num_edges + 2 * num_vertices)
             + num_vertices + num_edges
             + 8 * batch_sources * num_vertices)
 
@@ -528,26 +529,29 @@ class SingleDeviceBackend:
         self._counters["dispatches"].inc()
         self._counters["sources"].inc(real)
         out = fn(ga, jnp.asarray(padded))
-        trips = passes = None
-        if isinstance(out, tuple):       # rows, trip counts, passes
-            out, trips, passes = out
+        counts = ()
+        if isinstance(out, tuple):  # rows, trip counts, passes[, pushes]
+            out, *counts = out
             # queued now, the copies land as the program ends instead of
             # costing a host round trip of their own after the sync
-            trips.copy_to_host_async()
-            passes.copy_to_host_async()
+            for count in counts:
+                count.copy_to_host_async()
         with self._span("device_sync", kernel=kernel):
             rows = jax.block_until_ready(out)[:real]
-        if trips is not None:
+        if counts:
+            trips, *scalars = counts
             self._count_steps(kernel, np.asarray(trips), real,
-                              int(passes))
+                              *map(int, scalars))
         return rows
 
     def _count_steps(self, kernel: str, trips: np.ndarray, real: int,
-                     passes: int) -> None:
+                     passes: int, pushes: int | None = None) -> None:
         """Step and lane counters of one launch from its lanes' loop trip
         counts: the launch ran ``max(trips)`` steps over every padded
         lane, of which the real lanes needed ``sum(trips[:real])``, and
-        each step ran ``passes`` passes of the segmented reduction."""
+        each step ran ``passes`` passes of the segmented reduction;
+        ``pushes`` of the steps (BFS levels) pushed the frontier's
+        out-arcs instead of pulling over every in-arc."""
         steps, lanes = int(trips.max()), len(trips)
         m = self.metrics
         m.counter("engine_kernel_steps_total",
@@ -563,6 +567,11 @@ class SingleDeviceBackend:
                   "segmented-reduction passes the kernel programs ran "
                   "(per launch, passes per step x steps)",
                   kernel=kernel).inc(passes * steps)
+        if pushes is not None:
+            m.counter("engine_push_steps_total",
+                      "loop steps that pushed the frontier's out-arcs "
+                      "(BFS levels whose arcs fit the push slots)",
+                      kernel=kernel).inc(pushes)
         self.last_run_steps = {"steps": steps, "lanes": lanes}
 
     def _run_pr_spmv(self, handle: GraphHandle) -> jnp.ndarray:

@@ -167,7 +167,7 @@ def test_bfs_lanes_across_packed_words(lanes):
     # boundary keep their own depths and trip counts
     g = _random_directed(9, n=90, e=400)
     sources = np.random.default_rng(lanes).integers(0, 90, lanes)
-    rows, trips, _ = K.bfs_multi_steps(to_device(g),
+    rows, trips, *_ = K.bfs_multi_steps(to_device(g),
                                        jnp.asarray(sources, jnp.int32))
     for row, trip, s in zip(np.asarray(rows), np.asarray(trips), sources):
         want = bfs_levels(g, int(s))

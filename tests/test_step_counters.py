@@ -73,7 +73,7 @@ def test_counted_programs_keep_the_public_results():
     srcs = jnp.asarray(firsts, jnp.int32)
     for counted, public in ((K.bfs_multi_steps, K.bfs_multi),
                             (K.sssp_multi_steps, K.sssp_multi)):
-        rows, trips, passes = counted(ga, srcs)
+        rows, trips, passes, *_ = counted(ga, srcs)
         np.testing.assert_array_equal(np.asarray(rows),
                                       np.asarray(public(ga, srcs)))
         np.testing.assert_array_equal(np.asarray(trips), [7, 4, 2])
@@ -124,3 +124,36 @@ def test_segment_passes_are_passes_times_steps(kernel, graph, passes,
     assert snap["engine_kernel_steps_total"] == {f"kernel={kernel}": steps}
     assert snap["engine_segment_passes_total"] == {
         f"kernel={kernel}": passes * steps}
+
+
+def _path_into_hub(length, leaves):
+    """An undirected path 0 .. length-1 whose last vertex links to a hub
+    of ``leaves`` leaves: the path's levels hold two arcs, the hub's and
+    the leaves' more than E/16."""
+    hub = length
+    src = list(range(length)) + [hub] * leaves
+    dst = list(range(1, length)) + [hub] + list(range(hub + 1,
+                                                      hub + 1 + leaves))
+    return from_edges(hub + 1 + leaves, src + dst, dst + src, name="hub")
+
+
+def test_push_steps_are_the_levels_that_pushed():
+    # from the path's end: 30 path levels push, the hub's and the leaves'
+    # levels pull, in one launch of 32 levels
+    graph = _path_into_hub(30, 100)
+    backend = SingleDeviceBackend()
+    handle = backend.prepare(graph)
+    assert 101 > handle.arrays.num_edges // 16 > 2
+    backend.run(handle, "bfs", [0])
+    snap = backend.metrics.snapshot()["counters"]
+    assert snap["engine_kernel_steps_total"] == {"kernel=bfs": 32}
+    assert snap["engine_push_steps_total"] == {"kernel=bfs": 30}
+    _, _, _, pushes = K.bfs_multi_steps(handle.arrays,
+                                        jnp.asarray([0], jnp.int32))
+    assert int(pushes) == 30
+    # two launches add up; SSSP counts no push steps
+    backend.run(handle, "bfs", [0, 29])
+    backend.run(handle, "sssp", [0])
+    snap = backend.metrics.snapshot()["counters"]
+    assert snap["engine_push_steps_total"]["kernel=bfs"] > 30
+    assert "kernel=sssp" not in snap["engine_push_steps_total"]

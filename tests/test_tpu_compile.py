@@ -18,6 +18,7 @@ tests run under several workers.
 """
 from __future__ import annotations
 
+import math
 import os
 import re
 
@@ -65,7 +66,7 @@ def _graph(sharding, num_vertices: int, num_edges: int,
     return GraphArrays(arr(v + 1), arr(e), arr(e), arr(v + 1), arr(e),
                        arr(e), arr(v), arr(v), arr(e),
                        arr(v, jnp.bool_) if masks else None,
-                       arr(e, jnp.bool_) if masks else None, arr(e))
+                       arr(e, jnp.bool_) if masks else None, arr(e), arr(e))
 
 
 def _kron(scale: int) -> tuple[int, int]:
@@ -85,7 +86,8 @@ def _fits(compiled) -> object:
 
 
 def _multi_source(fn, kernel: str, sharding, scale: int, batch: int,
-                  scopes: tuple[str, ...] = ()):
+                  scopes: tuple[str, ...] = (),
+                  loop_scatter_axes: tuple[int, ...] = ()):
     v, e = _kron(scale)
     sources = jax.ShapeDtypeStruct((batch,), jnp.int32, sharding=sharding)
     compiled = jax.jit(fn).lower(_graph(sharding, v, e), sources).compile()
@@ -97,9 +99,14 @@ def _multi_source(fn, kernel: str, sharding, scale: int, batch: int,
         assert f"/{scope}/" in hlo, scope
     if scopes:
         # the level / round loop reduces over the dst-sorted in-CSR: no
-        # sort and no scatter runs inside it (the initial `.at[source]`
-        # scatter is outside the loop)
-        assert not _loop_sorts_and_scatters(hlo)
+        # sort runs inside it, and no scatter but over as many indices as
+        # one of ``loop_scatter_axes`` (BFS's push step, over its slots
+        # and the vertices; the initial `.at[source]` scatter is outside
+        # the loop)
+        found = _loop_sorts_and_scatters(hlo)
+        assert all(op == "scatter" and count in loop_scatter_axes
+                   for op, _, count in found), found
+        assert not loop_scatter_axes or found
     # the byte model the scheduler bounds batches with must cover what
     # the compiler actually allocates
     model = launch_bytes(kernel, batch, v, e)
@@ -108,15 +115,27 @@ def _multi_source(fn, kernel: str, sharding, scale: int, batch: int,
         f"modelled {model} B")
 
 
-def _loop_sorts_and_scatters(hlo: str) -> list[str]:
+def _loop_sorts_and_scatters(hlo: str) -> list[tuple[str, str, int]]:
     """The sort and scatter ops of a compiled program whose op_name puts
-    them inside a while loop's body."""
-    found = []
+    them inside a while loop's body, as (op, op_name, number of scatter
+    indices; 0 for a sort)."""
+    shapes, found = {}, []
     for line in hlo.splitlines():
-        op = re.search(r"= \S+ (sort|scatter)\(", line)
+        defined = re.match(r"\s*(?:ROOT )?(%\S+) = \w+\[([\d,]*)\]", line)
+        if defined:
+            shapes[defined.group(1)] = [int(d) for d in
+                                        defined.group(2).split(",") if d]
+        op = re.search(r"= \S+ (sort|scatter)\(([^)]*)\)", line)
         name = re.search(r'op_name="([^"]*)"', line)
         if op and name and "/while/body/" in name.group(1):
-            found.append(f"{op.group(1)}: {name.group(1)}")
+            count = 0
+            if op.group(1) == "scatter":
+                index = op.group(2).split(",")[1].strip()
+                vec = int(re.search(r"index_vector_dim=(\d+)",
+                                    line).group(1))
+                count = math.prod(d for i, d in enumerate(shapes[index])
+                                  if i != vec)
+            found.append((op.group(1), name.group(1), count))
     return found
 
 
@@ -130,9 +149,13 @@ def _smoke_batch(kernel: str, scale: int) -> int:
 
 def test_compile_bfs_multi(one_chip):
     # the program the engine serves: the rows plus each lane's trip count
+    # levels whose frontier's out-arcs fit E/16 slots push them: the
+    # loop's only scatters, over those slots and over the vertices
+    v, e = _kron(SMOKE_SCALE)
     _multi_source(K.bfs_multi_steps, "bfs", one_chip, SMOKE_SCALE,
                   _smoke_batch("bfs", SMOKE_SCALE),
-                  ("bfs_frontier_gather", "bfs_segment_reduce"))
+                  ("bfs_frontier_gather", "bfs_segment_reduce"),
+                  loop_scatter_axes=(e // 16, v))
 
 
 def test_compile_sssp_multi(one_chip):
